@@ -112,6 +112,9 @@ def main() -> None:
     args = ap.parse_args()
     assert not args.write_baseline or args.json, \
         "--write-baseline needs --json DIR"
+    from repro.common.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     wanted = args.sections.split(",") if args.sections else None
     known = {"kernels", "serving", "samsara", "fig_semantic", "fig_fused",

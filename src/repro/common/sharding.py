@@ -12,7 +12,6 @@ replicates the batch) are applied with ``rules_scope``.
 from __future__ import annotations
 
 import contextlib
-import inspect
 import threading
 from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
@@ -99,25 +98,6 @@ def mesh_scope(mesh: Optional[Mesh]):
             yield
     finally:
         _MESH = prev
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """Version-compat ``shard_map``: ``jax.shard_map`` where exposed,
-    falling back to ``jax.experimental.shard_map.shard_map`` (jax 0.4.x).
-    The replication-check kwarg is detected from the signature — the
-    top-level export and the ``check_rep`` -> ``check_vma`` rename landed
-    in different JAX releases."""
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        params = inspect.signature(sm).parameters
-        kw = "check_vma" if "check_vma" in params else "check_rep"
-    except (TypeError, ValueError):
-        kw = "check_vma" if hasattr(jax, "shard_map") else "check_rep"
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kw: check})
 
 
 def _filter_axes(val: AxisVal, mesh: Mesh) -> AxisVal:

@@ -11,6 +11,28 @@ import jax.numpy as jnp
 import numpy as np
 
 
+#: the repository checkout (this file is ``src/repro/common/utils.py``)
+REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                         "..", ".."))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Entry points (scripts, benchmarks, examples) call this once before
+    compiling anything; importing the library never does.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing
+    changes.  Otherwise the cache lives at the fixed
+    ``<repo>/.cache/jax_compile``, so every run of this checkout finds
+    what an earlier one stored."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(REPO_ROOT, ".cache", "jax_compile")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def scan_unroll(length: int) -> int:
     """Unroll factor for lax.scan loops.
 

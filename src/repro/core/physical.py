@@ -171,11 +171,7 @@ class PhysicalOptimizer:
         shrinking n down the unfused chain.  No catalog → no fusion:
         this decision is always measurement-backed, never a guess."""
         from repro.core.phases import SAMPLE_FRAMES, SAMPLE_SEED
-        from repro.streaming.fused import (
-            FUSABLE,
-            FusedPrefixOp,
-            fusable_segment,
-        )
+        from repro.streaming.fused import FusedPrefixOp, fusable_start
 
         report["fused_prefix"] = {"fused": False, "reason": "no catalog"}
         if catalog is None:
@@ -184,13 +180,7 @@ class PhysicalOptimizer:
         if mi is None:
             report["fused_prefix"] = {"fused": False, "reason": "no extract"}
             return
-        start = mi
-        while start > 0 and isinstance(plan.ops[start - 1], FUSABLE):
-            start -= 1
-        # every member is FUSABLE; trim from the left until the ordering
-        # constraints (Skip first, Detect last) hold too
-        while start < mi and not fusable_segment(plan.ops[start:mi]):
-            start += 1
+        start = fusable_start(plan.ops, mi)
         seg = plan.ops[start:mi]
         if len(seg) < 2:
             report["fused_prefix"] = {

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.common.sharding import shard_map_compat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,8 +94,8 @@ def gpipe(stage_fn: Callable[[Any, jax.Array], jax.Array], mesh: Mesh,
                                            is_leaf=lambda l: hasattr(
                                                l, "shape")),
                     P())
-        return shard_map_compat(local, mesh=mesh, in_specs=in_specs,
-                                out_specs=P(), check=False)(
+        return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                             out_specs=P(), check_vma=False)(
             stage_params, x)
 
     return pipelined

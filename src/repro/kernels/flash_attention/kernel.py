@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
@@ -127,15 +128,8 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def _scratch(rows: int, d: int):
     """VMEM scratch for (m, l, acc) online-softmax state."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        vmem = pltpu.VMEM
-    except Exception:  # pragma: no cover - CPU-only interpret fallback
-        vmem = functools.partial(pl.MemoryRef, memory_space=pl.ANY)
-
     return [
-        vmem((rows, LANES), jnp.float32),
-        vmem((rows, LANES), jnp.float32),
-        vmem((rows, d), jnp.float32),
+        pltpu.VMEM((rows, LANES), jnp.float32),
+        pltpu.VMEM((rows, LANES), jnp.float32),
+        pltpu.VMEM((rows, d), jnp.float32),
     ]

@@ -4,7 +4,9 @@ Computes mean |frame_t − frame_{t−1}| over a (RY × RX) grid of regions —
 the cheap "is anything happening here?" statistic the semantic optimizer's
 Skip(N, condition) operator evaluates before invoking the MLLM.
 
-Grid: (B, RY, RX); each program reduces one (C, rh, rw) region pair.
+Grid: (B,); each program reads one whole (C, H, W) frame pair and pools
+every region at once (``kernels/pixel.py``), so the block shapes equal the
+frame's own dimensions whatever the region grid.
 """
 from __future__ import annotations
 
@@ -14,11 +16,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.pixel import diff_grid
 
-def _diff_kernel(a_ref, b_ref, o_ref):
-    a = a_ref[0].astype(jnp.float32)
-    b = b_ref[0].astype(jnp.float32)
-    o_ref[0, 0, 0] = jnp.mean(jnp.abs(a - b)) / 255.0
+
+def _diff_kernel(a_ref, b_ref, o_ref, *, regions):
+    o_ref[0] = diff_grid(a_ref[0], b_ref[0], regions)
 
 
 def frame_diff_kernel(cur: jax.Array, prev: jax.Array, *, regions=(4, 4),
@@ -27,16 +29,12 @@ def frame_diff_kernel(cur: jax.Array, prev: jax.Array, *, regions=(4, 4),
     b, c, h, w = cur.shape
     ry, rx = regions
     assert h % ry == 0 and w % rx == 0
-    rh, rw = h // ry, w // rx
-
+    frame = pl.BlockSpec((1, c, h, w), lambda i: (i, 0, 0, 0))
     return pl.pallas_call(
-        _diff_kernel,
-        grid=(b, ry, rx),
-        in_specs=[
-            pl.BlockSpec((1, c, rh, rw), lambda b_, i, j: (b_, 0, i, j)),
-            pl.BlockSpec((1, c, rh, rw), lambda b_, i, j: (b_, 0, i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1), lambda b_, i, j: (b_, i, j)),
+        functools.partial(_diff_kernel, regions=regions),
+        grid=(b,),
+        in_specs=[frame, frame],
+        out_specs=pl.BlockSpec((1, ry, rx), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, ry, rx), jnp.float32),
         interpret=interpret,
     )(cur, prev)

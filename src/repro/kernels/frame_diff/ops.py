@@ -17,7 +17,7 @@ def _use_pallas() -> bool:
 @functools.partial(jax.jit, static_argnames=("regions", "interpret"))
 def frame_diff(cur: jax.Array, prev: jax.Array, *, regions=(4, 4),
                interpret: bool = False) -> jax.Array:
-    if _use_pallas() or interpret:
+    if interpret or _use_pallas():
         return frame_diff_kernel(cur, prev, regions=regions,
-                                 interpret=interpret or not _use_pallas())
+                                 interpret=interpret)
     return frame_diff_ref(cur, prev, regions=regions)

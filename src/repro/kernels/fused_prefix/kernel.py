@@ -12,21 +12,30 @@ kernel on the same device, inside the same jit.
 
 Grid: (B,).  VMEM per program: the raw frame pair as f32 plus the
 reduced intermediates — ≤ ~1 MiB for the 3×128×256 streaming shape, in
-budget.  W = 256 keeps the lane dimension aligned.
+budget.
 
-Stage math mirrors ``ref.fused_prefix_ref`` expression for expression
-(which in turn inlines the unfused operators' jitted bodies); the sweep
-test ``tests/test_kernels.py::test_fused_prefix_sweep`` pins
-interpret-mode output to the oracle.
+The diff and preprocess stages call the in-kernel helpers of the
+``frame_diff`` and ``fused_preprocess`` kernels (``kernels/pixel.py``), so
+on the chip the fused pass and the unfused ops compute those stages the
+same way; the other stages follow ``ref.fused_prefix_ref`` stage by
+stage.  The sweep test ``tests/test_kernels.py::test_fused_prefix_sweep``
+pins interpret-mode output to the oracle.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import pixel
+from repro.kernels.fused_prefix.ref import project_rowwise
+
+#: the normalization constants of ``FusedPreprocessOp`` (its defaults)
+_MEAN = (0.5, 0.5, 0.5)
+_STD = (0.25, 0.25, 0.25)
 
 
 def out_frame_shape(spec, shape: Tuple[int, int, int]
@@ -43,72 +52,76 @@ def out_frame_shape(spec, shape: Tuple[int, int, int]
     return c, h, w
 
 
-def _prefix_kernel(*refs, spec, sig_d: int):
+def _color_frac(ps, rgb, roi) -> jax.Array:
+    """Fraction of the ROI's pixels within distance 70 of ``rgb``
+    (``CheapColorFilterOp``'s statistic; normalized frames are mapped
+    back to the pixel range first, decided on the ROI's own maximum)."""
+    if roi is not None:
+        y0, x0, h, w = roi
+        ps = [p[y0:y0 + h, x0:x0 + w] for p in ps]
+    norm = pixel.frame_max(ps) <= 8.0
+    sq = None
+    for k, p in enumerate(ps):
+        p = jnp.where(norm, (p * 0.25 + 0.5) * 255.0, p)
+        d = (p - float(rgb[k])) ** 2
+        sq = d if sq is None else sq + d
+    near = (jnp.sqrt(sq) < 70.0).astype(jnp.float32)
+    return near.sum() / float(near.size)
+
+
+def _signature_grid(ps, gy: int, gx: int) -> List[jax.Array]:
+    """Per-channel (gy, gx) patch means of the gate signature, after the
+    per-frame raw-pixel normalization of ``TemporalSignature``."""
+    raw = pixel.frame_max(ps) > 8.0
+    h, w = ps[0].shape
+    out = []
+    for p in ps:
+        p = jnp.where(raw, (p / 255.0 - 0.5) / 0.25, p)
+        out.append(pixel.pool_sum(p, gy, gx) / float((h // gy) * (w // gx)))
+    return out
+
+
+def _prefix_kernel(*refs, spec):
     """One frame: walk the stages, writing each statistic's output ref."""
     it = iter(refs)
-    x_ref = next(it)
-    prev_ref = next(it) if any(s[0] == "diff" for s in spec) else None
-    d_ref = next(it) if any(s[0] == "diff" for s in spec) else None
+    has_diff = any(s[0] == "diff" for s in spec)
     ncolor = sum(1 for s in spec if s[0] == "color")
+    x_ref = next(it)
+    prev_ref = next(it) if has_diff else None
+    d_ref = next(it) if has_diff else None
     frac_ref = next(it) if ncolor else None
     o_ref = next(it)
     feat_ref = next(it) if any(s[0] == "signature" for s in spec) else None
 
-    cur = x_ref[0]                                    # (C, H, W)
-    ci = 0
+    ps = pixel.planes(x_ref[0])
+    fracs = []
     for stage in spec:
         kind = stage[0]
         if kind == "diff":
-            ry, rx = stage[1]
-            c, h, w = cur.shape
-            a = cur.astype(jnp.float32)
-            b = prev_ref[0].astype(jnp.float32)
-            dd = jnp.abs(a - b) / 255.0
-            dd = dd.reshape(c, ry, h // ry, rx, w // rx)
-            d_ref[0] = dd.mean(axis=(0, 2, 4))
+            d_ref[0] = pixel.diff_grid(x_ref[0], prev_ref[0], stage[1])
         elif kind == "color":
-            roi = stage[2]
-            x = cur
-            if roi is not None:
-                y0, x0, h, w = roi
-                x = x[:, y0:y0 + h, x0:x0 + w]
-            x = x.astype(jnp.float32)
-            norm = x.max() <= 8.0
-            x = jnp.where(norm, (x * 0.25 + 0.5) * 255.0, x)
-            # per-channel scalar arithmetic: Pallas kernels cannot
-            # capture array constants, so the target color stays Python
-            # floats (same trick as fused_preprocess's mean/std)
-            dist = jnp.sqrt(sum((x[k] - float(stage[1][k])) ** 2
-                                for k in range(x.shape[0])))
-            frac_ref[0, ci] = (dist < 70.0).astype(jnp.float32).mean()
-            ci += 1
+            fracs.append(_color_frac(ps, stage[1], stage[2]))
         elif kind == "crop":
             y0, x0, h, w = stage[1]
-            cur = cur[:, y0:y0 + h, x0:x0 + w]
+            ps = [p[y0:y0 + h, x0:x0 + w] for p in ps]
         elif kind == "preprocess":
             _, crop, factor, grey = stage
-            y0, x0, ch, cw = crop
-            c = cur.shape[0]
-            x = cur[:, y0:y0 + ch, x0:x0 + cw].astype(jnp.float32) / 255.0
-            x = x.reshape(c, ch // factor, factor,
-                          cw // factor, factor).mean(axis=(2, 4))
-            chans = [(x[k] - 0.5) / 0.25 for k in range(c)]
+            ps = pixel.preprocess(ps, crop, factor, _MEAN, _STD, grey)
             if grey:
-                lum = (0.299, 0.587, 0.114)
-                g = chans[0] * lum[0]
-                for k in range(1, c):
-                    g = g + chans[k] * lum[k]
-                chans = [g] * c                       # host-repeat inlined
-            cur = jnp.stack(chans, axis=0)
+                ps = ps * len(_MEAN)                  # host-repeat inlined
         elif kind == "signature":
-            gy, gx = stage[1]
-            c, h, w = cur.shape
-            x = cur.astype(jnp.float32)
-            raw = x.max() > 8.0
-            x = jnp.where(raw, (x / 255.0 - 0.5) / 0.25, x)
-            p = x.reshape(c, gy, h // gy, gx, w // gx)
-            feat_ref[0] = p.mean(axis=(2, 4)).reshape(sig_d)
-    o_ref[0] = cur.astype(o_ref.dtype)
+            for k, g in enumerate(_signature_grid(ps, *stage[1])):
+                feat_ref[0, k] = g
+    if ncolor:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, ncolor), 1)
+        row = jnp.zeros((1, ncolor), jnp.float32)
+        for j, f in enumerate(fracs):
+            row = jnp.where(lane == j, f, row)
+        frac_ref[0] = row
+    for k, p in enumerate(ps):
+        if jnp.issubdtype(o_ref.dtype, jnp.integer):
+            p = p.astype(jnp.int32)             # Mosaic: no f32 -> uint8
+        o_ref[0, k] = p.astype(o_ref.dtype)
 
 
 def fused_prefix_kernel(frames: jax.Array, prevs=None, proj=None, *,
@@ -121,48 +134,45 @@ def fused_prefix_kernel(frames: jax.Array, prevs=None, proj=None, *,
     ncolor = sum(1 for s in spec if s[0] == "color")
     oc, oh, ow = out_frame_shape(spec, (c, h, w))
 
-    gy = gx = sig_d = 0
-    if has_sig:
-        gy, gx = next(s[1] for s in spec if s[0] == "signature")
-        sig_d = oc * gy * gx
+    # every block spans its array's trailing dims: Mosaic takes any such
+    # block, where a (1, n) row block of a (B, n) array breaks its
+    # (8, 128) tiling rule — hence the (B, 1, n) / (B, C, gy, gx)
+    # layouts, reshaped back outside the kernel
+    def block(*dims):
+        return pl.BlockSpec((1,) + dims, lambda i: (i,) + (0,) * len(dims))
 
-    frame_spec = pl.BlockSpec((1, c, h, w), lambda i: (i, 0, 0, 0))
-    in_specs = [frame_spec] + ([frame_spec] if has_diff else [])
+    in_specs = [block(c, h, w)] * (2 if has_diff else 1)
     out_specs, out_shape = [], []
+
+    def out(dims, dtype):
+        out_specs.append(block(*dims))
+        out_shape.append(jax.ShapeDtypeStruct((b,) + dims, dtype))
+
     if has_diff:
-        ry, rx = next(s[1] for s in spec if s[0] == "diff")
-        out_specs.append(pl.BlockSpec((1, ry, rx), lambda i: (i, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((b, ry, rx), jnp.float32))
+        out(next(s[1] for s in spec if s[0] == "diff"), jnp.float32)
     if ncolor:
-        out_specs.append(pl.BlockSpec((1, ncolor), lambda i: (i, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((b, ncolor), jnp.float32))
-    out_dtype = jnp.float32 if any(s[0] == "preprocess" for s in spec) \
-        else frames.dtype
-    out_specs.append(pl.BlockSpec((1, oc, oh, ow),
-                                  lambda i: (i, 0, 0, 0)))
-    out_shape.append(jax.ShapeDtypeStruct((b, oc, oh, ow), out_dtype))
+        out((1, ncolor), jnp.float32)
+    out((oc, oh, ow), jnp.float32 if any(s[0] == "preprocess" for s in spec)
+        else frames.dtype)
     if has_sig:
-        out_specs.append(pl.BlockSpec((1, sig_d), lambda i: (i, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((b, sig_d), jnp.float32))
+        out((oc,) + next(s[1] for s in spec if s[0] == "signature"),
+            jnp.float32)
 
     args = (frames, prevs) if has_diff else (frames,)
-    outs = pl.pallas_call(
-        functools.partial(_prefix_kernel, spec=spec, sig_d=sig_d),
+    outs = list(pl.pallas_call(
+        functools.partial(_prefix_kernel, spec=spec),
         grid=(b,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-    )(*args)
-    outs = list(outs) if isinstance(outs, (tuple, list)) else [outs]
+    )(*args))
 
     d = outs.pop(0) if has_diff else None
-    fracs = tuple(outs.pop(0).T) if ncolor else ()
+    fracs = tuple(outs.pop(0)[:, 0].T) if ncolor else ()
     x = outs.pop(0)
     feats = emb = None
     if has_sig:
-        from repro.kernels.fused_prefix.ref import project_rowwise
-
-        feats = outs.pop(0)
+        feats = outs.pop(0).reshape(b, -1)
         emb = project_rowwise(feats, proj)
     return d, fracs, x, feats, emb

@@ -29,8 +29,8 @@ def fused_prefix(frames: jax.Array, prevs=None, proj=None, *, spec,
     (D, EMB_DIM) f32 (signature stage only); ``spec`` is the static
     stage tuple documented in ``ref.fused_prefix_ref``.  Returns
     ``(d, fracs, x, feats, emb)``."""
-    if _use_pallas() or interpret:
+    if interpret or _use_pallas():
         return fused_prefix_kernel(
             frames, prevs, proj, spec=spec,
-            interpret=interpret or not _use_pallas())
+            interpret=interpret)
     return fused_prefix_ref(frames, prevs, proj, spec=spec)

@@ -3,88 +3,57 @@
 This is the TPU-native realization of the Saṃsāra semantic-optimization
 data-reduction operators: instead of separate Crop → Downscale → Normalize
 passes (3× HBM round trips on the raw frame), a single kernel reads each raw
-uint8 tile once and emits the reduced bf16/f32 tile.
+uint8 frame once and emits the reduced f32 frame.
 
-Layout: frames are channels-first (B, C, H, W) uint8 (W lanes).  The crop is
-expressed in the BlockSpec index_map — crop offsets must be multiples of the
-input tile (the optimizer catalog quantizes crop regions accordingly).
-Downscale is area-averaging by an integer factor f.
+Layout: frames are channels-first (B, C, H, W) uint8 (W lanes).  The crop
+is a static slice of the loaded frame, so any in-frame region works.
+Downscale is area-averaging by an integer factor f (``kernels/pixel.py``).
 
-Grid: (B, H_out/Th, W_out/Tw).  VMEM per program:
-  in (C, Th·f, Tw·f) uint8 ≤ 3·128f·128f B (f=4 => 786KiB) — in budget.
+Grid: (B,).  VMEM per program: the raw (C, H, W) frame plus its f32
+planes — about 0.5 MiB for the 3×128×256 streaming shape.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import pixel
 
-def _preproc_kernel(x_ref, o_ref, *, factor: int, mean: Tuple[float, ...],
-                    std: Tuple[float, ...], grey: bool):
-    c, hf, wf = x_ref.shape[1], x_ref.shape[2], x_ref.shape[3]
-    th, tw = hf // factor, wf // factor
-    x = x_ref[0].astype(jnp.float32) / 255.0          # (C, Hf, Wf)
-    # area downscale
-    x = x.reshape(c, th, factor, tw, factor).mean(axis=(2, 4))
-    # per-channel affine with Python-static constants (no captured arrays)
-    chans = [(x[ci] - mean[ci]) / std[ci] for ci in range(c)]
-    if grey:
-        lum = (0.299, 0.587, 0.114)
-        out = chans[0] * lum[0]
-        for ci in range(1, c):
-            out = out + chans[ci] * lum[ci]
-        x = out[None]                                  # (1, Th, Tw)
-    else:
-        x = jnp.stack(chans, axis=0)
-    o_ref[0] = x.astype(o_ref.dtype)
+
+def _preproc_kernel(x_ref, o_ref, *, crop, factor: int,
+                    mean: Tuple[float, ...], std: Tuple[float, ...],
+                    grey: bool):
+    out = pixel.preprocess(pixel.planes(x_ref[0]), crop, factor, mean, std,
+                           grey)
+    for k, p in enumerate(out):
+        o_ref[0, k] = p.astype(o_ref.dtype)
 
 
 def fused_preprocess_kernel(
     frames: jax.Array, *, crop: Tuple[int, int, int, int], factor: int = 1,
     mean: Tuple[float, ...] = (0.5, 0.5, 0.5),
     std: Tuple[float, ...] = (0.25, 0.25, 0.25), grey: bool = False,
-    tile: Tuple[int, int] = (32, 128), out_dtype=jnp.float32,
-    interpret: bool = False,
+    out_dtype=jnp.float32, interpret: bool = False,
 ) -> jax.Array:
     """frames (B, C, H, W) uint8; crop (y0, x0, h, w) -> (B, C', h/f, w/f)."""
     b, c, h, w = frames.shape
     y0, x0, ch, cw = crop
     assert y0 + ch <= h and x0 + cw <= w, "crop outside frame"
-
-    def _fit_tile(want: int, offset: int, size: int, f: int) -> int:
-        """Largest input-tile (multiple of f) dividing both offset and size."""
-        import math
-
-        align = math.gcd(offset, size) if offset else size
-        d = min(want * f, align)
-        while d > f and (align % d or d % f):
-            d -= f
-        assert d >= f and align % d == 0 and d % f == 0, (
-            "crop not tileable; the catalog quantizes regions")
-        return d
-
-    th, tw = tile
-    in_th = _fit_tile(th, y0, ch, factor)
-    in_tw = _fit_tile(tw, x0, cw, factor)
-    th, tw = in_th // factor, in_tw // factor
-    h_out, w_out = ch // factor, cw // factor
+    assert ch % factor == 0 and cw % factor == 0, "crop not divisible by f"
     c_out = 1 if grey else c
-    oy, ox = y0 // in_th, x0 // in_tw
+    h_out, w_out = ch // factor, cw // factor
 
     return pl.pallas_call(
-        functools.partial(_preproc_kernel, factor=factor, mean=mean, std=std,
-                          grey=grey),
-        grid=(b, h_out // th, w_out // tw),
-        in_specs=[
-            pl.BlockSpec((1, c, in_th, in_tw),
-                         lambda b_, i, j: (b_, 0, oy + i, ox + j)),
-        ],
-        out_specs=pl.BlockSpec((1, c_out, th, tw),
-                               lambda b_, i, j: (b_, 0, i, j)),
+        functools.partial(_preproc_kernel, crop=crop, factor=factor,
+                          mean=mean, std=std, grey=grey),
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, c, h, w), lambda i: (i, 0, 0, 0))],
+        out_specs=pl.BlockSpec((1, c_out, h_out, w_out),
+                               lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, c_out, h_out, w_out), out_dtype),
         interpret=interpret,
     )(frames)

@@ -23,9 +23,9 @@ def fused_preprocess(frames: jax.Array, *, crop: Tuple[int, int, int, int],
                      std: Tuple[float, ...] = (0.25, 0.25, 0.25),
                      grey: bool = False, out_dtype=jnp.float32,
                      interpret: bool = False) -> jax.Array:
-    if _use_pallas() or interpret:
+    if interpret or _use_pallas():
         return fused_preprocess_kernel(
             frames, crop=crop, factor=factor, mean=mean, std=std, grey=grey,
-            out_dtype=out_dtype, interpret=interpret or not _use_pallas())
+            out_dtype=out_dtype, interpret=interpret)
     return fused_preprocess_ref(frames, crop=crop, factor=factor, mean=mean,
                                 std=std, grey=grey, out_dtype=out_dtype)

@@ -11,6 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _int8_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_ref, *, nk: int):
@@ -55,15 +56,8 @@ def int8_matmul_kernel(x: jax.Array, w: jax.Array, sx: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[_acc(bm, bn)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
     )(x, w, sx, sw)
 
 
-def _acc(bm: int, bn: int):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM((bm, bn), jnp.int32)
-    except Exception:  # pragma: no cover
-        return jax.ShapeDtypeStruct((bm, bn), jnp.int32)

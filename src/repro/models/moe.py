@@ -32,7 +32,6 @@ from repro.common.sharding import (
     current_mesh,
     dp_axis_names,
     logical_to_mesh,
-    shard_map_compat,
 )
 from repro.common.utils import ceil_div
 from repro.models.param import ParamSpec
@@ -191,7 +190,7 @@ def apply_moe(params: Dict[str, Any], x: jax.Array, moe: MoEConfig,
             # outside via mean of replicated value
             return y.reshape(x3d.shape), aux
 
-        y, aux = shard_map_compat(
+        y, aux = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(
@@ -202,7 +201,7 @@ def apply_moe(params: Dict[str, Any], x: jax.Array, moe: MoEConfig,
                 P("model", None, fsdp_axes if fsdp_axes else None),
             ),
             out_specs=(x_spec, P()),
-            check=False,
+            check_vma=False,
         )(x, params["router"], params["w_in"], params["w_gate"],
           params["w_out"])
 

@@ -111,13 +111,6 @@ from repro.streaming.mllm import make_extract_fn, variant_models
 from repro.streaming.operators import OpContext, _bucket_pad
 
 
-def _is_ready(x) -> bool:
-    """Non-blocking completion probe; a backend without ``is_ready``
-    reports ready (materialization then simply blocks)."""
-    ready = getattr(x, "is_ready", None)
-    return bool(ready()) if ready is not None else True
-
-
 class _InFlightChunk:
     """One launched forward: device-side predictions for a coalesced chunk
     plus the bookkeeping to fulfil its requests and recycle its staging
@@ -142,7 +135,7 @@ class _InFlightChunk:
         self.delay_polls = 0
 
     def ready(self) -> bool:
-        return all(_is_ready(v) for v in self.preds.values())
+        return all(v.is_ready() for v in self.preds.values())
 
     def block(self) -> None:
         jax.block_until_ready(self.preds)

@@ -66,6 +66,18 @@ def fusable_segment(ops: List[Op]) -> bool:
         and sum(isinstance(o, DetectOp) for o in ops) <= 1
 
 
+def fusable_start(ops: List[Op], end: int) -> int:
+    """Start of the longest fusable segment ``ops[start:end]`` — the
+    FUSABLE run just before ``ops[end]`` (the extract), trimmed from the
+    left until the ordering constraints hold; ``end`` if there is none."""
+    start = end
+    while start > 0 and isinstance(ops[start - 1], FUSABLE):
+        start -= 1
+    while start < end and not fusable_segment(ops[start:end]):
+        start += 1
+    return start
+
+
 @dataclasses.dataclass
 class FusedPrefixOp(Op):
     """One-device-pass execution of a fusable prefix segment.

@@ -193,6 +193,15 @@ def variant_models(ctx) -> Dict[str, Tuple["StreamMLLM", Any]]:
     }
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def _extract(mllm: StreamMLLM, params, frames):
+    x = frames.astype(jnp.float32)
+    raw = x.reshape(x.shape[0], -1).max(axis=1) > 8.0
+    x = jnp.where(raw[:, None, None, None], (x / 255.0 - 0.5) / 0.25, x)
+    out = mllm.forward(params, x)
+    return {k: jnp.argmax(v, -1) for k, v in out.items()}
+
+
 def make_extract_fn(mllm: StreamMLLM, params):
     """Jitted batched union extract: frames -> argmax prediction per task.
 
@@ -205,18 +214,13 @@ def make_extract_fn(mllm: StreamMLLM, params):
     forward, and each row must come out bitwise identical to a solo run.
     Zero padding rows classify as "normalized" and are sliced off by the
     caller, so they never perturb real rows.
+
+    The weights are an argument of one jitted function keyed by the model
+    object, not constants of a fresh closure: every operator and server
+    that opens the same model (and the pruned variant, which shares it)
+    reuses one compiled program per frame shape.
     """
-
-    @jax.jit
-    def run(frames):
-        x = frames.astype(jnp.float32)
-        raw = x.reshape(x.shape[0], -1).max(axis=1) > 8.0
-        x = jnp.where(raw[:, None, None, None],
-                      (x / 255.0 - 0.5) / 0.25, x)
-        out = mllm.forward(params, x)
-        return {k: jnp.argmax(v, -1) for k, v in out.items()}
-
-    return run
+    return functools.partial(_extract, mllm, params)
 
 
 def distill_loss(student: StreamMLLM, teacher_out: Dict[str, jax.Array],

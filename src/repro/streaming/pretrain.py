@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.utils import enable_compile_cache
 from repro.configs import get_config
 from repro.data.tollbooth import (BRANDS, COLORS, PLATE_CHARS,
                                   TollBoothStream)
@@ -161,7 +162,8 @@ def stream_models(quick: bool = False) -> OpContext:
     """The examples' single entry point: cached full-quality stream
     models, or the tiny un-cached quick set under ``--quick`` (CI smoke).
     One implementation so the quick-mode setup cannot drift between
-    examples."""
+    examples; it also turns on the persistent compilation cache."""
+    enable_compile_cache()
     if quick:
         print("quick mode: training tiny stream models…")
         return quick_stream_models(verbose=False)
