@@ -13,6 +13,11 @@ clock reads with ``if obs.enabled:`` — so un-observed serving pays only
 empty attribute checks and stays bitwise identical to pre-instrumentation
 behavior (enforced by ``tests/test_obs.py``).
 
+The served path's spans go through ``repro.obs.spans.span``, which has a
+second sink: while a ``jax.profiler`` session records, each span is also
+written into the profiler's trace, on the clock of the device ops,
+whatever ``obs`` is (see that module).
+
 Usage::
 
     obs = Observability()                       # tracing + metrics + SLO

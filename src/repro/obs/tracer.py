@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import json
 import sys
+import threading
 import time
 from typing import Any, Dict, List, Optional
 
@@ -87,6 +88,8 @@ class Tracer(NullTracer):
         self._t1 = np.zeros(capacity, np.int64)
         self._n = np.zeros(capacity, np.int64)
         self._idx = 0                  # total events ever recorded
+        #: the fan-out's tails record spans from pool threads
+        self._lock = threading.Lock()
 
     # -- recording (hot path) -------------------------------------------
     def now(self) -> int:
@@ -94,15 +97,16 @@ class Tracer(NullTracer):
 
     def _store(self, kind: str, name: str, cat: str, track: str,
                t0_ns: int, t1_ns: int, n: int) -> None:
-        i = self._idx % self.capacity
-        self._kind[i] = kind
-        self._name[i] = name
-        self._cat[i] = cat
-        self._track[i] = track
-        self._t0[i] = t0_ns
-        self._t1[i] = t1_ns
-        self._n[i] = n
-        self._idx += 1
+        with self._lock:
+            i = self._idx % self.capacity
+            self._idx += 1
+            self._kind[i] = kind
+            self._name[i] = name
+            self._cat[i] = cat
+            self._track[i] = track
+            self._t0[i] = t0_ns
+            self._t1[i] = t1_ns
+            self._n[i] = n
 
     def span(self, name: str, cat: str, t0_ns: int,
              t1_ns: Optional[int] = None, track: str = "main",

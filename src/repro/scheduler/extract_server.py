@@ -69,15 +69,21 @@ place (never rebuilt per read).  Two entries are *gauges*, not counters:
 state on every read, so they stay truthful across ``reset_stats()``
 instead of freezing at whatever the last in-place update wrote.
 
-Observability (``repro.obs``): with an enabled ``Observability``
-(``obs=`` or ``ctx.obs``) the server records the device half of every
-frame's lifecycle — per-request ``queue_wait`` spans (submit → launch)
-and a ``queue_wait_ms/<feed>`` histogram, ``staging`` / ``dispatch``
-spans on the ``server`` track, a ``forward[variant]`` span per chunk on
-the ``device`` track (launch → observed completion) feeding a
-``forward_ms`` histogram, and ``inflight`` / ``queue_depth`` counter
+Spans (``repro.obs.spans``): the server records the device half of every
+frame's lifecycle — per-request ``queue_wait`` spans (submit → the launch
+that carries the request), ``staging`` (with the ``h2d_bytes`` of the
+staged input) and ``dispatch[variant]`` spans per launch, a
+``forward[variant]`` span per chunk (launch → observed completion),
+``block`` while the host waits on a forward in ``wait()``/``drain()``,
+and ``harvest`` while ``_retire`` retires one.  Each carries the ids it
+covers: ``feed``, ``req`` (the request number), ``mb`` (the micro-batch,
+set by the caller on the request) and ``fwd`` (the forward number).  They
+go to the profiler's trace while a ``jax.profiler`` session records, and
+to the ring buffer with an enabled ``Observability`` (``obs=`` or
+``ctx.obs``), which also gets a ``queue_wait_ms/<feed>`` and a
+``forward_ms`` histogram and ``inflight`` / ``queue_depth`` counter
 samples — the occupancy timeline that shows whether double buffering
-actually overlaps.  Un-observed servers pay only no-op calls.
+actually overlaps.  With neither, a span site costs one check.
 
 The observed ``forward`` span is an upper bound on device time — it
 includes however long the runtime took to poll the completion — so every
@@ -107,6 +113,7 @@ from repro.faults import (
     resolve_faults,
 )
 from repro.obs import resolve_obs
+from repro.obs.spans import NULL_SPAN, span
 from repro.streaming.mllm import make_extract_fn, variant_models
 from repro.streaming.operators import OpContext, _bucket_pad
 
@@ -117,7 +124,8 @@ class _InFlightChunk:
     buffer once the device retires it."""
 
     __slots__ = ("preds", "reqs", "buf_key", "buf", "completed", "_np",
-                 "t_launch", "variant", "total", "delay_polls")
+                 "seq", "span", "d2h_bytes", "variant", "total",
+                 "delay_polls")
 
     def __init__(self, preds, reqs: List["ExtractRequest"],
                  buf_key=None, buf=None):
@@ -127,7 +135,9 @@ class _InFlightChunk:
         self.buf = buf                    # staging buffer, held until retire
         self.completed = False
         self._np: Optional[Dict[str, np.ndarray]] = None
-        self.t_launch = 0                 # obs stamp: forward launch (ns)
+        self.seq = 0                      # the server's forward number
+        self.span = NULL_SPAN             # ``forward``: launch → retire
+        self.d2h_bytes = 0                # what materialize() copied
         self.variant = ""
         self.total = 0
         #: injected artificial device latency: the chunk's completion is
@@ -146,6 +156,7 @@ class _InFlightChunk:
         if self._np is None:
             self._np = {k: np.asarray(v) for k, v in self.preds.items()}
             self.preds = {}               # release device references
+            self.d2h_bytes = sum(v.nbytes for v in self._np.values())
         return self._np
 
 
@@ -186,6 +197,14 @@ class GatedExtractRequest:
         return self.adm.ready
 
     @property
+    def fwd(self) -> int:
+        return self.inner.fwd if self.inner is not None else -1
+
+    @property
+    def d2h_bytes(self) -> int:
+        return self.inner.d2h_bytes if self.inner is not None else 0
+
+    @property
     def result(self) -> Optional[Dict[str, np.ndarray]]:
         if not self.done:
             return None
@@ -200,8 +219,8 @@ class ExtractRequest:
     numpy materialization, shared per coalesced chunk, on first access)."""
 
     __slots__ = ("variant", "frames", "feed", "_chunk", "_offset",
-                 "t_submit", "attempts", "isolate", "failed", "not_before",
-                 "fault_event")
+                 "seq", "mb", "span", "attempts", "isolate", "failed",
+                 "not_before", "fault_event")
 
     def __init__(self, variant: str, frames: np.ndarray, feed: str = ""):
         self.variant = variant            # big | small | pruned
@@ -209,7 +228,10 @@ class ExtractRequest:
         self.feed = feed
         self._chunk: Optional[_InFlightChunk] = None
         self._offset = 0
-        self.t_submit = 0                 # obs stamp: enqueue time (ns)
+        self.seq = 0                      # the server's request number
+        #: the caller's micro-batch id (first frame index), a span stat
+        self.mb = -1
+        self.span = NULL_SPAN             # ``queue_wait``: submit → launch
         #: retry accounting: launches attempted / earliest dispatch round
         #: the next attempt is eligible (exponential backoff) / whether a
         #: failed chunk's members must relaunch one-per-chunk so a
@@ -235,6 +257,19 @@ class ExtractRequest:
     def done(self) -> bool:
         """The forward completed — ``result`` will not block."""
         return self._chunk is not None and self._chunk.completed
+
+    @property
+    def fwd(self) -> int:
+        """Number of the forward that carries the request; -1 before."""
+        return self._chunk.seq if self._chunk is not None else -1
+
+    @property
+    def d2h_bytes(self) -> int:
+        """Bytes of the chunk's device→host copy, once ``result`` made it:
+        counted on the request at the chunk's head, 0 on the others, so
+        a sum over requests counts each copy once."""
+        return self._chunk.d2h_bytes \
+            if self._chunk is not None and self._offset == 0 else 0
 
     @property
     def result(self) -> Optional[Dict[str, np.ndarray]]:
@@ -265,6 +300,8 @@ class PendingResume:
     batch: Any
     req: Union["ExtractRequest", "GatedExtractRequest"]
     n: int
+    #: the micro-batch's id in spans (index of its first frame)
+    mb: int = -1
 
 
 def settle_fifo(pendings: List[Tuple[Any, PendingResume]],
@@ -349,6 +386,8 @@ class SharedExtractServer:
         self.device_probe_every = device_probe_every
         self._probe_seq = 0                   # forwards since last probe
         self._dispatch_seq = 0                # retry backoff clock (rounds)
+        self._req_seq = 0                     # requests enqueued (span ids)
+        self._fwd_seq = 0                     # forwards launched (span ids)
         self._defers: Dict[Tuple, int] = {}   # bucket key -> deferred calls
         self._fns: Dict[str, Any] = {}
         self._queue: List[ExtractRequest] = []
@@ -458,8 +497,10 @@ class SharedExtractServer:
     def _enqueue(self, variant: str, frames: np.ndarray,
                  feed: str) -> ExtractRequest:
         req = ExtractRequest(variant=variant, frames=frames, feed=feed)
-        if self.obs.enabled:
-            req.t_submit = self.obs.now()
+        self._req_seq += 1
+        req.seq = self._req_seq
+        req.span = span(self.obs, "queue_wait", "queue", f"feed:{feed}",
+                        n=req.n, feed=feed, req=req.seq)
         if self.faults.enabled:
             req.fault_event = self.faults.next_event("forward", feed)
         self._queue.append(req)
@@ -490,6 +531,7 @@ class SharedExtractServer:
             self._queue.remove(req)
         except ValueError:
             return False
+        req.span.close()
         self._pending_reqs[req.feed] -= 1
         self._pending_frames[req.feed] -= req.n
         self._pending_reqs_total -= 1
@@ -538,6 +580,7 @@ class SharedExtractServer:
             r.isolate = True
             if r.attempts >= self.retry.max_attempts:
                 r.failed = True
+                r.span.close()
                 self.stats["retry_exhausted"] += 1
                 # terminal: dispatch removes it from the queue below
                 self._pending_reqs[r.feed] -= 1
@@ -575,47 +618,60 @@ class SharedExtractServer:
                     self._chunk_failed(variant, chunk)
                     return False
                 delay = max(delay, f[1])        # latency
-        t_stage = obs.now() if obs.enabled else 0
         total = sum(r.n for r in chunk)
         bucket = _bucket_pad(total)
         shape = chunk[0].frames.shape[1:]
         dtype = chunk[0].frames.dtype
-        if len(chunk) == 1 and chunk[0].n == bucket:
-            # an exactly-full single request needs no staging copy
-            dev = jnp.asarray(chunk[0].frames)
-            buf_key = buf = None
-            self.stats["staging_skipped"] += 1
-        else:
-            buf_key = (bucket,) + tuple(shape) + (dtype.str,)
-            buf = self._acquire_staging(buf_key, bucket, shape, dtype)
-            off = 0
-            for r in chunk:
-                buf[off:off + r.n] = r.frames
-                off += r.n
-            if bucket > total:
-                # padding rows must classify as "normalized" in the jitted
-                # program — a reused buffer otherwise carries stale frames
-                buf[total:bucket] = 0
-            dev = jnp.asarray(buf)
-        t_disp = obs.now() if obs.enabled else 0
-        if faults.enabled:
-            # with the injector live, a real forward exception follows
-            # the same retry path as an injected one; without it, errors
-            # propagate exactly as before (no behavior change)
-            try:
-                preds = self._fn(variant)(dev)
-            except AssertionError:
-                raise
-            except Exception:
-                if buf is not None:
-                    self._staging.setdefault(buf_key, []).append(buf)
-                self._chunk_failed(variant, chunk)
-                return False
-        else:
-            preds = self._fn(variant)(dev)  # async dispatch: returns now
+        self._fwd_seq += 1
+        seq = self._fwd_seq
+        ids = dict(fwd=seq, variant=variant, bucket=bucket, frames=total)
+        with span(obs, "staging", "staging", "server", n=total,
+                  **ids) as s:
+            if len(chunk) == 1 and chunk[0].n == bucket:
+                # an exactly-full single request needs no staging copy
+                host = chunk[0].frames
+                buf_key = buf = None
+                self.stats["staging_skipped"] += 1
+            else:
+                buf_key = (bucket,) + tuple(shape) + (dtype.str,)
+                buf = host = self._acquire_staging(buf_key, bucket, shape,
+                                                   dtype)
+                off = 0
+                for r in chunk:
+                    buf[off:off + r.n] = r.frames
+                    off += r.n
+                if bucket > total:
+                    # padding rows must classify as "normalized" in the
+                    # jitted program — a reused buffer otherwise carries
+                    # stale frames
+                    buf[total:bucket] = 0
+            dev = jnp.asarray(host)
+            s.set(h2d_bytes=host.nbytes)
+        with span(obs, f"dispatch[{variant}]", "dispatch", "server",
+                  n=bucket, **ids):
+            if faults.enabled:
+                # with the injector live, a real forward exception follows
+                # the same retry path as an injected one; without it,
+                # errors propagate exactly as before (no behavior change)
+                try:
+                    preds = self._fn(variant)(dev)
+                except AssertionError:
+                    raise
+                except Exception:
+                    if buf is not None:
+                        self._staging.setdefault(buf_key, []).append(buf)
+                    self._chunk_failed(variant, chunk)
+                    return False
+            else:
+                preds = self._fn(variant)(dev)  # async dispatch: returns now
         fl = _InFlightChunk(preds, list(chunk), buf_key, buf)
+        fl.seq = seq
+        fl.span = span(obs, f"forward[{variant}]", "forward", "device",
+                       n=total, fwd=seq, variant=variant)
         fl.variant = variant
         fl.total = total
+        for r in chunk:
+            r.span.close(fwd=seq, mb=r.mb)
         if delay:
             fl.delay_polls = delay
             self.stats["latency_faults"] += 1
@@ -623,19 +679,12 @@ class SharedExtractServer:
                 obs.tracer.instant(f"fault:latency[{variant}]", "fault",
                                    track="device", n=total)
         if obs.enabled:
-            fl.t_launch = obs.now()
-            tr = obs.tracer
-            tr.span("staging", "staging", t_stage, t_disp,
-                    track="server", n=total)
-            tr.span(f"dispatch[{variant}]", "dispatch", t_disp,
-                    fl.t_launch, track="server", n=bucket)
+            t_launch = fl.span.t0
             for r in chunk:
-                if r.t_submit:
-                    tr.span("queue_wait", "queue", r.t_submit, fl.t_launch,
-                            track=f"feed:{r.feed}", n=r.n)
+                if r.span.t0:
                     obs.metrics.observe(
                         f"queue_wait_ms/{r.feed}",
-                        (fl.t_launch - r.t_submit) / 1e6, r.n)
+                        (r.span.t1 - r.span.t0) / 1e6, r.n)
             if self.device_probe_every and not delay:
                 # device-accurate forward timing: every Nth forward is
                 # probed — block on a one-element sentinel sliced from
@@ -647,9 +696,10 @@ class SharedExtractServer:
                     sentinel = next(iter(fl.preds.values()))[:1]
                     jax.block_until_ready(sentinel)
                     t_done = obs.now()
-                    tr.span(f"forward_device[{variant}]", "forward",
-                            fl.t_launch, t_done, track="device", n=total)
-                    dev_ms = (t_done - fl.t_launch) / 1e6
+                    obs.tracer.span(f"forward_device[{variant}]", "forward",
+                                    t_launch, t_done, track="device",
+                                    n=total)
+                    dev_ms = (t_done - t_launch) / 1e6
                     obs.metrics.observe("forward_device_ms", dev_ms)
                     obs.metrics.observe(
                         f"forward_device_ms/{variant}", dev_ms)
@@ -806,23 +856,21 @@ class SharedExtractServer:
 
     # ------------------------------------------------------------------
     def _retire(self, fl: _InFlightChunk) -> None:
-        fl.completed = True
-        if fl.buf is not None:
-            # the device consumed the staging input; recycle it
-            self._staging.setdefault(fl.buf_key, []).append(fl.buf)
-            fl.buf = None
-        if fl.t_launch:
+        obs = self.obs
+        with span(obs, "harvest", "forward", "server", fwd=fl.seq):
+            fl.completed = True
+            if fl.buf is not None:
+                # the device consumed the staging input; recycle it
+                self._staging.setdefault(fl.buf_key, []).append(fl.buf)
+                fl.buf = None
             # launch → observed completion: an upper bound on device time
             # (includes the poll interval), which is the honest quantity
             # for occupancy reasoning — the host couldn't have used the
             # result any earlier
-            obs = self.obs
-            t1 = obs.now()
-            obs.tracer.span(f"forward[{fl.variant}]", "forward",
-                            fl.t_launch, t1, track="device", n=fl.total)
-            obs.metrics.observe(
-                "forward_ms", (t1 - fl.t_launch) / 1e6)
-            fl.t_launch = 0
+            fl.span.close()
+            if fl.span.t0:
+                obs.metrics.observe(
+                    "forward_ms", (fl.span.t1 - fl.span.t0) / 1e6)
 
     def poll(self) -> int:
         """Non-blocking: retire every in-flight forward whose device work
@@ -883,6 +931,12 @@ class SharedExtractServer:
                     f"not_before={r.not_before} (round {self._dispatch_seq})")
         return "no queued or in-flight work"
 
+    def block_oldest(self) -> None:
+        """Block the host until the oldest in-flight forward completes."""
+        fl = self._inflight[0]
+        with span(self.obs, "block", "forward", "server", fwd=fl.seq):
+            fl.block()
+
     def wait(self) -> int:
         """Block until at least one in-flight forward completes
         (dispatching queued work first when nothing is in flight); returns
@@ -897,7 +951,7 @@ class SharedExtractServer:
             self.dispatch()
         deadline = time.monotonic() + self.drain_timeout_s
         while self._inflight:
-            self._inflight[0].block()
+            self.block_oldest()
             retired = self.poll()
             if retired:
                 return retired
@@ -925,7 +979,7 @@ class SharedExtractServer:
             launched = self.dispatch()
             retired = 0
             if self._inflight:
-                self._inflight[0].block()
+                self.block_oldest()
                 retired = self.poll()
             if launched or retired:
                 deadline = time.monotonic() + self.drain_timeout_s

@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.core.costs import op_cost_key
 from repro.faults import OPEN, CircuitBreaker, resolve_faults
+from repro.obs.spans import span
 from repro.scheduler.extract_server import (
     PendingResume,
     SharedExtractServer,
@@ -142,6 +143,9 @@ class _GroupExec:
         #: coalescing into it, so spans from all feeds land in one trace
         self.obs = server.obs
         self._track = f"feed:{feed}"
+        self._prefix_spans = [
+            "prefix:fused" if isinstance(op, FusedPrefixOp)
+            else f"prefix:{op.name}" for op in self.exe.prefix]
         #: shared one-slot newest-arrival stamp (ns): the pull loop writes
         #: it at ingest, ``_fan_out`` reads it at emit — their difference
         #: is the feed's staleness (how far the freshest served answer
@@ -179,22 +183,22 @@ class _GroupExec:
     # ------------------------------------------------------------------
     def start(self, batch: Batch) -> Optional[_Pending]:
         """Advance a fresh micro-batch; returns a continuation if the
-        prefix suspended at an extract, else None (fan-out done)."""
-        return self._advance(dict(batch), 0)
+        prefix suspended at an extract, else None (fan-out done).  The
+        micro-batch's id in every span is the index of its first frame."""
+        idx = batch["idx"]
+        return self._advance(dict(batch), 0, int(idx[0]) if len(idx) else -1)
 
     def resume(self, p: _Pending) -> Optional[_Pending]:
         op = self.exe.prefix[p.op_index]
-        obs = self.obs
-        if obs.enabled:
-            t0 = obs.now()
-            batch = op.apply_preds(p.batch, p.req.result, p.n)
-            obs.tracer.span("resume", "resume", t0, obs.now(),
-                            track=self._track, n=p.n)
-        else:
-            batch = op.apply_preds(p.batch, p.req.result, p.n)
-        return self._advance(batch, p.op_index + 1)
+        with span(self.obs, "resume", "resume", self._track, n=p.n,
+                  feed=self.feed, mb=p.mb) as s:
+            preds = p.req.result          # the device→host copy
+            if s:
+                s.set(fwd=p.req.fwd, d2h_bytes=p.req.d2h_bytes)
+            batch = op.apply_preds(p.batch, preds, p.n)
+        return self._advance(batch, p.op_index + 1, p.mb)
 
-    def _advance(self, batch: Batch, i: int) -> Optional[_Pending]:
+    def _advance(self, batch: Batch, i: int, mb: int) -> Optional[_Pending]:
         obs = self.obs
         while i < len(self.exe.prefix):
             op = self.exe.prefix[i]
@@ -208,64 +212,63 @@ class _GroupExec:
                 sig = batch.pop("_sig", None)
                 req = self.server.submit(variant, batch["frames"],
                                          feed=self.feed, sig=sig)
-                return _Pending(op_index=i, batch=batch, req=req, n=n)
-            if obs.enabled:
-                t0 = obs.now()
-                batch = broadcast_windows(op.process(batch), self.windows)
-                t1 = obs.now()
-                fused = isinstance(op, FusedPrefixOp)
-                obs.tracer.span("prefix:fused" if fused
-                                else f"prefix:{op.name}", "prefix", t0,
-                                t1, track=self._track, n=n)
-                if n > 0:
-                    # measured per-op accounting keyed the way the cost
-                    # catalog keys predictions — what PlanAudit joins
-                    # against (wall µs per invocation; frames in; rows
-                    # surviving) to reconcile marginal cost + pass rate
-                    key = op_cost_key(op)
-                    obs.metrics.observe(f"op_wall_us/{key}",
-                                        (t1 - t0) / 1e3)
-                    obs.metrics.inc(f"op_frames/{key}", n)
-                    obs.metrics.inc(f"op_rows_out/{key}",
-                                    int(batch["frames"].shape[0]))
-                if fused:
-                    # per-stage attribution: the chain collapsed to one
-                    # dispatch, so surviving-row counts per fused stage
-                    # are the remaining stage-level signal
-                    for sname, rows_in, rows_out in op.last_stage_counts:
-                        obs.metrics.set_gauge(
-                            f"prefix_fused/{self.feed}/{sname}/in",
-                            rows_in)
-                        obs.metrics.set_gauge(
-                            f"prefix_fused/{self.feed}/{sname}/out",
-                            rows_out)
-            else:
-                batch = broadcast_windows(op.process(batch), self.windows)
+                queued = getattr(req, "inner", req)   # None: all cached
+                if queued is not None:
+                    queued.mb = mb
+                return _Pending(op_index=i, batch=batch, req=req, n=n,
+                                mb=mb)
+            with span(obs, self._prefix_spans[i], "prefix", self._track,
+                      n=n, feed=self.feed, mb=mb) as s:
+                out = op.process(batch)
+                if s:
+                    h2d, d2h = op.link_bytes
+                    s.set(n_out=int(out["frames"].shape[0]),
+                          h2d_bytes=h2d, d2h_bytes=d2h)
+            batch = broadcast_windows(out, self.windows)
+            if obs.enabled and n > 0:
+                # measured per-op accounting keyed the way the cost
+                # catalog keys predictions — what PlanAudit joins
+                # against (wall µs per invocation; frames in; rows
+                # surviving) to reconcile marginal cost + pass rate
+                key = op_cost_key(op)
+                obs.metrics.observe(f"op_wall_us/{key}", (s.t1 - s.t0) / 1e3)
+                obs.metrics.inc(f"op_frames/{key}", n)
+                obs.metrics.inc(f"op_rows_out/{key}",
+                                int(batch["frames"].shape[0]))
+            if obs.enabled and isinstance(op, FusedPrefixOp):
+                # per-stage attribution: the chain collapsed to one
+                # dispatch, so surviving-row counts per fused stage are
+                # the remaining stage-level signal
+                for sname, rows_in, rows_out in op.last_stage_counts:
+                    obs.metrics.set_gauge(
+                        f"prefix_fused/{self.feed}/{sname}/in", rows_in)
+                    obs.metrics.set_gauge(
+                        f"prefix_fused/{self.feed}/{sname}/out", rows_out)
             i += 1
-        self._fan_out(batch)
+        self._fan_out(batch, mb)
         return None
 
-    def _fan_out(self, batch: Batch) -> None:
+    def _fan_out(self, batch: Batch, mb: int = -1) -> None:
+        """Every query's tail on the batch; each tail is a ``tail`` span
+        with the query's id.  Flush batches carry ``mb`` -1."""
         obs = self.obs
-        if not obs.enabled:
-            fan_out_tails(self.exe.tails, batch, self.counts, self.windows,
-                          parallel=self.parallel_tails)
-            return
-        t0 = obs.now()
+        n = len(batch["idx"])
+        queries = self.exe.queries
         fan_out_tails(self.exe.tails, batch, self.counts, self.windows,
-                      parallel=self.parallel_tails)
-        t1 = obs.now()
-        obs.tracer.span("tail", "tail", t0, t1, track=self._track,
-                        n=len(batch["idx"]))
+                      parallel=self.parallel_tails,
+                      tail_span=lambda qi: span(
+                          obs, "tail", "tail", self._track, n=n,
+                          feed=self.feed, mb=mb, query=queries[qi]))
         tb = batch.get("_obs_t0")
         if tb:
             # frame latency: ingest stamp → emit; staleness: emit − the
             # feed's newest arrival (exceeds latency whenever fresher
             # frames arrived while this batch was in flight)
+            t1 = obs.now()
             stale = (t1 - self.arrival[0]) / 1e6 if self.arrival[0] \
                 else None
             obs.slo.record(self.feed, (t1 - tb) / 1e6, stale,
-                           n=int(batch.get("_obs_n", len(batch["idx"]))))
+                           n=int(batch.get("_obs_n", n)))
 
     def flush(self) -> None:
         """End of stream.  Flush batches carry no frames (only buffered
@@ -704,7 +707,7 @@ class MultiStreamRuntime:
         while not req.done and not req.failed:
             self.server.dispatch()
             if self.server._inflight:
-                self.server._inflight[0].block()
+                self.server.block_oldest()
             self.server.poll()
         return not req.failed
 
@@ -898,26 +901,24 @@ class MultiStreamRuntime:
                     self._snap_feed(fs)           # opportunistic, quiescent
                 take = min(self.micro_batch, remaining[fs.name])
                 obs = self.obs
-                t_pull = obs.now() if obs.enabled else 0
-                if self._chaos:
-                    got = self._ingest(fs, take)
-                    if got[0] == "stall":
-                        continue   # the feed produced nothing this round
-                    if got[0] == "lost":
-                        # delivery retries exhausted: quarantine first
-                        # (healthy in-flight frames settle and serve),
-                        # then account the lost batch itself
-                        self._trip(fs,
-                                   "ingest delivery retries exhausted")
-                        self._degrade_range(fs, fs.source_index,
-                                            fs.source_index + take)
-                        fs.source_index += take
-                        remaining[fs.name] -= take
-                        progressed = True
-                        continue
-                    frames, labels = got[1], got[2]
-                else:
-                    frames, labels = fs.feed.stream.batch(take)
+                with span(obs, "ingest", "ingest", f"feed:{fs.name}",
+                          n=take, feed=fs.name, mb=fs.source_index) as pull:
+                    got = self._ingest(fs, take) if self._chaos \
+                        else ("ok", *fs.feed.stream.batch(take))
+                if got[0] == "stall":
+                    continue       # the feed produced nothing this round
+                if got[0] == "lost":
+                    # delivery retries exhausted: quarantine first
+                    # (healthy in-flight frames settle and serve), then
+                    # account the lost batch itself
+                    self._trip(fs, "ingest delivery retries exhausted")
+                    self._degrade_range(fs, fs.source_index,
+                                        fs.source_index + take)
+                    fs.source_index += take
+                    remaining[fs.name] -= take
+                    progressed = True
+                    continue
+                frames, labels = got[1], got[2]
                 fs.labels.extend(labels)
                 batch = {"frames": frames,
                          "idx": np.arange(fs.source_index,
@@ -932,12 +933,9 @@ class MultiStreamRuntime:
                     # lifecycle stamps ride the batch dict (every op
                     # copies it, so they survive to fan-out); the shared
                     # arrival slot feeds the staleness measure
-                    t_arr = obs.now()
-                    obs.tracer.span("ingest", "ingest", t_pull, t_arr,
-                                    track=f"feed:{fs.name}", n=take)
-                    batch["_obs_t0"] = t_arr
+                    batch["_obs_t0"] = pull.t1
                     batch["_obs_n"] = take
-                    fs.arrival[0] = t_arr
+                    fs.arrival[0] = pull.t1
                 fs.source_index += take
                 remaining[fs.name] -= take
                 for g in fs.groups:

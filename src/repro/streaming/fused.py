@@ -191,6 +191,7 @@ class FusedPrefixOp(Op):
         frames = batch["frames"]
         n = frames.shape[0]
         if n == 0:
+            self.link_bytes = (0, 0)
             return batch
         prevs = self._skip.prev_frames(frames) \
             if self._skip is not None else None
@@ -198,6 +199,13 @@ class FusedPrefixOp(Op):
         d, fracs, x, p, feats, emb = run(
             jnp.asarray(frames),
             jnp.asarray(prevs) if prevs is not None else None)
+        # what comes back to the host below: every stage's statistic,
+        # the frames, and the gate signature
+        down = [x, p, *fracs, *((feats, emb) if self.sig else ()),
+                d if self._skip is not None else None]
+        self.link_bytes = (
+            frames.nbytes + (prevs.nbytes if prevs is not None else 0),
+            sum(a.nbytes for a in down if a is not None))
 
         # host side: replay each stage's *decision* in chain order —
         # Skip's stateful loop advances the member op itself

@@ -36,10 +36,11 @@ from __future__ import annotations
 import dataclasses
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from repro.obs.spans import NULL_SPAN
 from repro.streaming.operators import (
     Batch,
     Op,
@@ -72,21 +73,25 @@ def _fanout_pool() -> ThreadPoolExecutor:
 def fan_out_tails(tails: List[List[Op]], batch: Batch,
                   counts: List[Dict[str, int]],
                   windows: List[List[Dict[str, Any]]],
-                  parallel: bool = True) -> None:
+                  parallel: bool = True,
+                  tail_span: Optional[Callable[[int], Any]] = None) -> None:
     """Push one fully-advanced prefix batch through every per-query tail.
 
     Each tail owns its op instances and writes only its own ``counts[qi]``
     / ``windows[qi]`` slot, and operators copy-on-write the shared batch
     dict — so the tails are embarrassingly parallel.  ``parallel=False``
     keeps the sequential loop (single tail, or debugging).
+    ``tail_span(qi)``, where given, opens the span (``repro.obs.spans``)
+    that covers query ``qi``'s tail, its sink call included.
     """
     def one(qi: int) -> None:
         b = batch
-        for op in tails[qi]:
-            counts[qi][op.name] += len(b["idx"])
-            b = op.process(b)
-            if "window_results" in b:
-                windows[qi].extend(b.pop("window_results"))
+        with tail_span(qi) if tail_span is not None else NULL_SPAN:
+            for op in tails[qi]:
+                counts[qi][op.name] += len(b["idx"])
+                b = op.process(b)
+                if "window_results" in b:
+                    windows[qi].extend(b.pop("window_results"))
 
     if not parallel or len(tails) <= 1:
         for qi in range(len(tails)):

@@ -65,6 +65,10 @@ class Op:
 
     name: str = dataclasses.field(default="", init=False)
 
+    #: (host→device, device→host) bytes the last ``process`` call moved
+    #: across the host link; ops that run device programs set it
+    link_bytes = (0, 0)
+
     def open(self, ctx: "OpContext") -> None:  # pragma: no cover - interface
         pass
 
@@ -242,9 +246,12 @@ class SkipOp(Op):
         frames = batch["frames"]
         n = frames.shape[0]
         if n == 0:
+            self.link_bytes = (0, 0)
             return batch
         # one batched kernel call: frame i vs frame i-1 (first vs carry)
-        d = np.asarray(self._diff(frames, self.prev_frames(frames)))
+        prev = self.prev_frames(frames)
+        d = np.asarray(self._diff(frames, prev))
+        self.link_bytes = (frames.nbytes + prev.nbytes, d.nbytes)
         return _mask_batch(batch, self.keep_from_diff(frames, d))
 
     def reset(self):
@@ -324,13 +331,17 @@ class FusedPreprocessOp(Op):
             (",grey]" if self.grey else "]")
 
     def open(self, ctx: OpContext) -> None:
-        self._fn = jax.jit(functools.partial(
+        # ``fused_preprocess`` is jitted with these as static arguments,
+        # so its program is named ``jit_fused_preprocess`` in traces
+        self._fn = functools.partial(
             fused_preprocess, crop=self.crop, factor=self.factor,
-            grey=self.grey))
+            grey=self.grey)
 
     def process(self, batch: Batch) -> Batch:
         batch = dict(batch)
-        out = np.asarray(self._fn(jnp.asarray(batch["frames"])))
+        frames = batch["frames"]
+        out = np.asarray(self._fn(jnp.asarray(frames)))
+        self.link_bytes = (frames.nbytes, out.nbytes)
         if self.grey:
             out = np.repeat(out, 3, axis=1)
         batch["frames"] = out
@@ -375,12 +386,14 @@ class CheapColorFilterOp(Op):
 
     def process(self, batch: Batch) -> Batch:
         if batch["frames"].shape[0] == 0:
+            self.link_bytes = (0, 0)
             return batch
         roi_frames = batch["frames"]
         if self.roi is not None:
             y0, x0, h, w = self.roi
             roi_frames = roi_frames[:, :, y0:y0 + h, x0:x0 + w]
         frac = np.asarray(self._frac(jnp.asarray(roi_frames)))
+        self.link_bytes = (roi_frames.nbytes, frac.nbytes)
         return _mask_batch(batch, frac >= self.min_frac)
 
 
@@ -410,8 +423,10 @@ class DetectOp(Op):
 
     def process(self, batch: Batch) -> Batch:
         if batch["frames"].shape[0] == 0:
+            self.link_bytes = (0, 0)
             return batch
         p = np.asarray(self._run(jnp.asarray(batch["frames"])))
+        self.link_bytes = (batch["frames"].nbytes, p.nbytes)
         return _mask_batch(batch, p >= self.threshold)
 
 

@@ -205,6 +205,25 @@ def test_disabled_path_overhead_bounded_under_one_percent():
     assert overhead < 0.01
 
 
+def test_span_site_without_session_bounded_under_one_percent():
+    # with no profiler session and NULL_OBS a span site is one call that
+    # checks both sinks and returns the falsy NULL_SPAN; bound it against
+    # a pessimistic profile: 16 span sites per frame (one micro-batch's
+    # ingest, prefix ops, queue wait, resume, tails and launch, as if
+    # every frame were its own micro-batch) at 200 frames/s
+    from repro.obs.spans import NULL_SPAN, span
+    assert span(NULL_OBS, "ingest", "ingest", "feed:a") is NULL_SPAN
+    reps = 100_000
+    t0 = time.perf_counter_ns()
+    for i in range(reps):
+        with span(NULL_OBS, "prefix:skip", "prefix", "feed:a", n=16,
+                  feed="a", mb=i) as s:
+            if s:
+                s.set(n_out=16)
+    per_site_ns = (time.perf_counter_ns() - t0) / reps
+    assert (16 * per_site_ns) / 5e6 < 0.01
+
+
 # ---------------------------------------------------------------------------
 # (c) with models: bitwise identity + server gauges
 # ---------------------------------------------------------------------------
@@ -305,3 +324,163 @@ def test_warmup_histograms_dropped_on_reset(ctx):
     srv.reset_stats()                    # e.g. after warmup
     assert obs.metrics.histogram("forward_ms").count == 0
     assert obs.metrics.histogram("queue_wait_ms/a").count == 0
+
+
+# ---------------------------------------------------------------------------
+# (d) the served path's spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+#: span names the served path writes (prefix/dispatch/forward by prefix)
+_SPANS = ("ingest", "queue_wait", "staging", "block", "harvest", "resume",
+          "tail")
+_SPAN_PREFIXES = ("prefix:", "dispatch[", "forward[")
+_CROP = (64, 0, 64, 256)
+
+
+def _ours(name):
+    return name in _SPANS or name.startswith(_SPAN_PREFIXES)
+
+
+def _prefixed(qid):
+    """A catalog plan behind the benchmark's prefix: Skip, then the fused
+    crop/downscale/normalize."""
+    from repro.queries import get_query
+    from repro.streaming.operators import (FusedPreprocessOp, MLLMExtractOp,
+                                           SkipOp)
+    plan = get_query(qid).naive_plan()
+    plan.insert_after_source(SkipOp(amount=3, roi=_CROP, regions=(4, 8)))
+    plan.insert_before(MLLMExtractOp, FusedPreprocessOp(crop=_CROP,
+                                                        factor=2))
+    return plan
+
+
+def _serve_prefixed(ctx, obs=None, frames=32):
+    from repro.data import TollBoothStream, VolleyballStream
+    from repro.queries import get_query
+    from repro.scheduler import Feed, MultiStreamRuntime
+
+    if obs is not None:
+        ctx = dataclasses.replace(ctx, obs=obs)
+    feeds = [Feed("tb0", TollBoothStream(seed=5),
+                  [_prefixed(q) for q in ("Q2", "Q6")]),
+             Feed("vb0", VolleyballStream(seed=5),
+                  [get_query("Q12").naive_plan()])]
+    ms = MultiStreamRuntime(feeds, ctx, micro_batch=16)
+    return ms, ms.run(frames)
+
+
+def _outputs(res):
+    return {(f, q): (r.outputs, r.window_results)
+            for f, fr in res.feeds.items() for q, r in fr.per_query.items()}
+
+
+def _profiled(ctx, trace_dir, obs=None):
+    """Serve under a profiler session; the program's host spans from the
+    ``.xplane.pb`` as (name, start, end, stats)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        ms, res = _serve_prefixed(ctx, obs)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(trace_dir / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if _ours(ev.name):
+                    spans.append((ev.name, ev.start_ns,
+                                  ev.start_ns + ev.duration_ns,
+                                  dict(ev.stats)))
+    return ms, res, spans
+
+
+def test_served_spans_reach_the_profiler_trace_with_their_ids(ctx,
+                                                              tmp_path):
+    base = _outputs(_serve_prefixed(ctx)[1])
+    obs = Observability(slo_target_ms=10_000.0)
+    ms, res, spans = _profiled(ctx, tmp_path, obs)
+    assert _outputs(res) == base          # both sinks on: same answers
+    by = {}
+    for name, a, b, st in spans:
+        by.setdefault(name.split("[")[0] if name.startswith(
+            ("dispatch[", "forward[")) else name, []).append((a, b, st))
+    skip = "prefix:skip[3,no_car]"
+    pre = f"prefix:fused_preprocess[{_CROP},/2]"
+    assert {"ingest", skip, pre, "queue_wait", "staging", "dispatch",
+            "forward", "block", "harvest", "resume", "tail"} <= set(by)
+    assert all(n in _SPANS + ("dispatch", "forward")
+               or n.startswith("prefix:") for n in by)
+    want = {"ingest": {"feed", "mb", "n"},
+            skip: {"feed", "mb", "n", "n_out", "h2d_bytes", "d2h_bytes"},
+            pre: {"feed", "mb", "n", "n_out", "h2d_bytes", "d2h_bytes"},
+            "queue_wait": {"feed", "mb", "req", "n", "fwd"},
+            "staging": {"fwd", "variant", "bucket", "frames", "n",
+                        "h2d_bytes"},
+            "dispatch": {"fwd", "variant", "bucket", "frames", "n"},
+            "block": {"fwd", "n"}, "harvest": {"fwd", "n"},
+            "resume": {"feed", "mb", "fwd", "n", "d2h_bytes"},
+            "tail": {"feed", "mb", "query", "n"}}
+    for name, keys in want.items():
+        for _, _, st in by[name]:
+            assert set(st) == keys, (name, st)
+    # the pull of every micro-batch, and Skip's upload of each frame and
+    # of its predecessor
+    frame = 3 * 128 * 256
+    assert sorted(st["mb"] for _, _, st in by["ingest"]
+                  if st["feed"] == "tb0") == [0, 16]
+    for _, _, st in by[skip]:
+        assert st["h2d_bytes"] == 2 * st["n"] * frame
+        assert st["d2h_bytes"] == st["n"] * 4 * 8 * 4
+    for _, _, st in by[pre]:
+        assert st["h2d_bytes"] == st["n"] * frame
+        assert st["d2h_bytes"] == st["n_out"] * 3 * 32 * 128 * 4
+    # each queue wait ends at the launch that carries it
+    launches = {st["fwd"]: (a, b, st) for a, b, st in by["dispatch"]}
+    assert len(launches) == len(by["dispatch"])
+    carried = {}
+    for a, b, st in by["queue_wait"]:
+        la, lb, lst = launches[st["fwd"]]
+        assert a <= la and b >= lb
+        carried[st["fwd"]] = carried.get(st["fwd"], 0) + st["n"]
+    assert carried == {f: st["frames"] for f, (_, _, st) in launches.items()}
+    for name in ("staging", "block", "harvest"):
+        assert {st["fwd"] for _, _, st in by[name]} <= set(launches)
+    assert {st["fwd"] for _, _, st in by["harvest"]} == set(launches)
+    assert {st["query"] for _, _, st in by["tail"]} == {"Q2", "Q6", "Q12"}
+    # the ring buffer holds the same spans, under the same names
+    ring = sorted(e["name"] for e in obs.tracer.events()
+                  if e["kind"] == "X" and _ours(e["name"]))
+    assert ring == sorted(name for name, _, _, _ in spans)
+    moved = sum(st.get(k, 0) for _, _, _, st in spans
+                for k in ("h2d_bytes", "d2h_bytes"))
+    assert obs.metrics.counter("link_bytes/h2d").value + \
+        obs.metrics.counter("link_bytes/d2h").value == moved
+
+
+def test_unobserved_unprofiled_serving_records_no_span(ctx, tmp_path,
+                                                        monkeypatch):
+    """No session and ``NULL_OBS``: no span object is made and the answers
+    are bitwise those of a profiled run; a profiled run under
+    ``NULL_OBS`` never takes the device probe."""
+    import repro.obs.spans as spans_mod
+    made = []
+
+    class Counted(spans_mod.Span):
+        __slots__ = ()
+
+        def __init__(self, *a):
+            made.append(a[1])
+            super().__init__(*a)
+
+    ms, profiled, spans = _profiled(ctx, tmp_path)
+    assert spans and ms.server._probe_seq == 0
+    monkeypatch.setattr(spans_mod, "Span", Counted)
+    _, plain = _serve_prefixed(ctx)
+    assert made == []
+    assert _outputs(plain) == _outputs(profiled)
