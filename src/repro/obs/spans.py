@@ -34,7 +34,7 @@ trace.
 from __future__ import annotations
 
 import time
-from typing import Any
+from typing import Any, Optional
 
 from jax.profiler import TraceAnnotation
 
@@ -113,10 +113,15 @@ class Span(NullSpan):
         if self.t0:
             self._count(stats)
 
-    def close(self, **stats: Any) -> None:
-        """End the span, adding ``stats``; a second close is a no-op."""
+    def close(self, track: Optional[str] = None, **stats: Any) -> None:
+        """End the span, adding ``stats``; a second close is a no-op.  A
+        ``track`` or an ``n`` given here replaces the one it was opened
+        with (a span whose work is known only at its end)."""
+        if track is not None:
+            self._track = track
         if stats:
             self.set(**stats)
+            self._n = stats.get("n", self._n)
         if self._tm is not None:
             self._tm.__exit__(None, None, None)
             self._tm = None

@@ -9,12 +9,28 @@ across feeds, every group's extract requests route through one
 ``SharedExtractServer`` that coalesces them into shape-bucketed batched
 forwards — K feeds cost one forward per coalesced batch instead of K.
 
-Scheduling is round-robin over feeds at micro-batch granularity (the
-starting feed rotates every round so no feed systematically front-runs the
-coalescing window), with per-stream backpressure: a feed whose un-fulfilled
-extract continuations reach its budget of ``max_pending × n_groups`` is
-skipped until the server drains, so one stalled/bursty feed cannot grow
-the request queue unboundedly while the others starve.
+Each feed's source is pulled on a hand-over thread of its own
+(``_Handover``): the thread pulls the micro-batch the loop asked for and
+hands it over in a slot that holds one; the loop asks for the next once
+it has taken and started the last, so a feed is pulled at most one
+micro-batch ahead, and a loop that falls behind shows one micro-batch
+later as a late pull.  Scheduling is round-robin over feeds at
+micro-batch granularity (the starting feed rotates every round so no feed
+systematically front-runs the coalescing window): a round takes the
+micro-batch each feed has handed over, with per-stream backpressure — a
+feed whose un-fulfilled extract continuations reach its budget of
+``max_pending × n_groups`` is skipped until the server drains, so one
+stalled/bursty feed cannot grow the request queue unboundedly while the
+others starve.  A feed is taken when its next micro-batch is due.  A
+source that states when its frames are due (a camera at its rate, whose
+``due_ns(idx)`` gives the due time of frame ``idx``) hands a micro-batch
+over once its last frame is due, so one that is not handed over yet is
+not due yet: the feed sits the round out, and the loop dispatches and
+harvests meanwhile.  A source that states no due times (a file, a
+generator, a backlog) has every frame due now: the loop waits for its
+hand-over, as it waited when it pulled the frames itself.  When there is
+nothing at all to launch, harvest or resume, the loop blocks on the
+condition every hand-over signals until one fills.
 
 Execution is suspension-based: a group advances each micro-batch through
 its prefix until an ``MLLMExtractOp``, parks the batch as a continuation
@@ -30,8 +46,10 @@ coalesced forwards asynchronously (``SharedExtractServer.dispatch``),
 ``poll``s for completions, and resumes exactly the continuations whose
 forwards finished — so round *k*'s source batching, prefix ops and tail
 fan-out overlap round *k−1*'s device forwards, double-buffered under the
-server's ``max_inflight`` cap.  The loop blocks (``server.wait``) only
-when no feed can progress and nothing polled ready; the synchronous
+server's ``max_inflight`` cap.  The loop blocks on the device
+(``server.wait``) only when no feed took a micro-batch and nothing
+polled ready, and on the hand-overs only when the server holds nothing
+queued or in flight; the synchronous
 ``_drain_all`` barrier survives for warmup, end-of-run and flush.
 ``pipelined=False`` restores the lock-step drain (the baseline the
 ``fig_pipeline`` benchmark measures against).
@@ -39,6 +57,7 @@ when no feed can progress and nothing polled ready; the synchronous
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from typing import Any, Dict, List, Optional, Union
 
@@ -278,11 +297,94 @@ class _GroupExec:
                      self._fan_out)
 
 
+class _Handover:
+    """One feed's source, pulled on a thread of its own during a run.
+
+    ``request(n)`` asks for the next ``n`` frames; the thread pulls them
+    (``stream.batch(n)``) and hands them over in a slot that holds one
+    micro-batch, which ``take()`` empties.  Nothing is pulled that
+    was not asked for, and nothing is asked for while a micro-batch is
+    being pulled or waits in the slot.  An exception the source raises is
+    handed over in its place and re-raised by ``take()``.  Every hand-over
+    of a run shares one condition, which the loop also waits on."""
+
+    def __init__(self, name: str, stream, cond: threading.Condition):
+        self._stream = stream
+        self._cond = cond
+        self._want = 0             # frames asked for, pull not begun
+        self._busy = False         # asked for, not handed over yet
+        self._slot: Any = None     # (frames, labels) or the exception
+        self._stop = False
+        #: the source states its frames' due times (``due_ns``): a
+        #: micro-batch not handed over yet is not due yet
+        self.live = callable(getattr(stream, "due_ns", None))
+        self._thread = threading.Thread(target=self._serve, daemon=True,
+                                        name=f"handover:{name}")
+        self._thread.start()
+
+    @property
+    def ready(self) -> bool:
+        """A micro-batch (or the source's exception) waits in the slot."""
+        return self._slot is not None
+
+    @property
+    def pending(self) -> bool:
+        """Something was asked for and not taken yet."""
+        return self._busy or self._slot is not None
+
+    def request(self, n: int) -> None:
+        with self._cond:
+            if not self.pending:
+                self._want, self._busy = n, True
+                self._cond.notify_all()
+
+    def take(self):
+        """Empty the slot, waiting for it to fill (``request`` first)."""
+        with self._cond:
+            self._cond.wait_for(lambda: self._slot is not None)
+            got, self._slot = self._slot, None
+        if isinstance(got, BaseException):
+            raise got
+        return got
+
+    def discard(self) -> None:
+        """Let a pull under way finish and drop what it handed over: the
+        stream is the caller's to reposition afterwards."""
+        with self._cond:
+            self._cond.wait_for(lambda: not self._busy)
+            self._slot = None
+
+    def close(self) -> None:
+        """Stop the thread and join it (after a pull under way ends)."""
+        with self._cond:
+            self._stop = True
+            self._cond.notify_all()
+        self._thread.join()
+
+    def _serve(self) -> None:
+        cond = self._cond
+        while True:
+            with cond:
+                cond.wait_for(lambda: self._stop or self._want)
+                if self._stop:
+                    return
+                n, self._want = self._want, 0
+            try:
+                got = self._stream.batch(n)
+            except BaseException as e:       # re-raised by take()
+                got = e
+            with cond:
+                self._slot, self._busy = got, False
+                cond.notify_all()
+
+
 class _FeedState:
     def __init__(self, feed: Feed, groups: List[_GroupExec],
                  arrival: Optional[list] = None):
         self.feed = feed
         self.groups = groups
+        #: the feed's ``_Handover`` while ``run`` is under way, else None
+        self.handover: Optional[_Handover] = None
         self.source_index = 0
         self.labels: List[Dict[str, Any]] = []
         self.pendings: List[tuple] = []      # (group, _Pending) FIFO
@@ -642,6 +744,9 @@ class MultiStreamRuntime:
         flowing through the shared server."""
         obs = self.obs
         gate = self.server.gate
+        # a quarantined feed holds nothing pulled ahead: recovery
+        # repositions the stream, and the outage accounts those frames
+        fs.handover.discard()
         # let healthy in-flight work finish first: an *ingest* trip
         # leaves the extract path intact, so frames already accepted can
         # still be served exactly once — only an extract trip (a failed
@@ -723,6 +828,7 @@ class MultiStreamRuntime:
         start = snap["next_pull"]
         target = fs.replay_to
         stream = fs.feed.stream
+        fs.handover.discard()        # no pull under way while it seeks
         stream.reset()
         if start:
             stream.batch(start)
@@ -787,10 +893,13 @@ class MultiStreamRuntime:
             obs.metrics.inc(f"faults/recoveries/{fs.name}", 1)
 
     def _ingest(self, fs: _FeedState, take: int) -> tuple:
-        """One guarded pull: returns ``("ok", frames, labels)``,
-        ``("stall",)`` — the feed produced nothing this round — or
+        """One guarded take from the feed's hand-over: returns
+        ``("ok", frames, labels)``, ``("stall",)`` — the feed produced
+        nothing this round, and its micro-batch stays in the slot — or
         ``("lost",)`` when corrupt-delivery retries are exhausted (the
-        caller accounts the frames and trips the breaker)."""
+        caller accounts the frames and trips the breaker).  The schedule
+        is drawn here, on the loop thread, one event per turn, so the
+        fault log keeps the order of the turns."""
         fi = self.faults
         ev = fi.next_event("source", fs.name)
         f = fi.fault_at("source", fs.name, "", ev)
@@ -800,7 +909,7 @@ class MultiStreamRuntime:
                 self.obs.tracer.instant("fault:stall", "fault",
                                         track=f"feed:{fs.name}", n=take)
             return ("stall",)
-        frames, labels = fs.feed.stream.batch(take)
+        frames, labels = fs.handover.take()
         if f is None:
             return ("ok", frames, labels)
         # corrupt transport: bounded redelivery against the same event —
@@ -880,97 +989,161 @@ class MultiStreamRuntime:
 
         remaining = dict(frames_by_feed)
         t0 = time.perf_counter()
-        rnd = 0
-        while any(remaining.values()) or \
-                any(fs.pendings for fs in self._feeds):
-            order = self._feeds[rnd % len(self._feeds):] + \
-                self._feeds[:rnd % len(self._feeds)]
-            progressed = False
-            for fs in order:
-                if self._chaos:
-                    ct = self._chaos_turn(fs, remaining)
-                    if ct is not None:      # trip / quarantine / probe
-                        progressed = progressed or ct
-                        continue
-                if remaining[fs.name] <= 0:
-                    continue
-                if len(fs.pendings) >= self.max_pending * len(fs.groups):
-                    continue                      # per-stream backpressure
-                if self._chaos and not fs.tickets and not fs.pendings \
-                        and rnd % self.snapshot_every == 0:
-                    self._snap_feed(fs)           # opportunistic, quiescent
-                take = min(self.micro_batch, remaining[fs.name])
-                obs = self.obs
-                with span(obs, "ingest", "ingest", f"feed:{fs.name}",
-                          n=take, feed=fs.name, mb=fs.source_index) as pull:
-                    got = self._ingest(fs, take) if self._chaos \
-                        else ("ok", *fs.feed.stream.batch(take))
-                if got[0] == "stall":
-                    continue       # the feed produced nothing this round
-                if got[0] == "lost":
-                    # delivery retries exhausted: quarantine first
-                    # (healthy in-flight frames settle and serve), then
-                    # account the lost batch itself
-                    self._trip(fs, "ingest delivery retries exhausted")
-                    self._degrade_range(fs, fs.source_index,
-                                        fs.source_index + take)
-                    fs.source_index += take
-                    remaining[fs.name] -= take
-                    progressed = True
-                    continue
-                frames, labels = got[1], got[2]
-                fs.labels.extend(labels)
-                batch = {"frames": frames,
-                         "idx": np.arange(fs.source_index,
-                                          fs.source_index + take)}
-                if self._chaos:
-                    # frame-range ticket: retired once every group's
-                    # fan-out for this micro-batch completes — the
-                    # outstanding set defines ``served_upto``
-                    fs.tickets[fs.source_index] = len(fs.groups)
-                    batch["_ticket"] = fs.source_index
-                if obs.enabled:
-                    # lifecycle stamps ride the batch dict (every op
-                    # copies it, so they survive to fan-out); the shared
-                    # arrival slot feeds the staleness measure
-                    batch["_obs_t0"] = pull.t1
-                    batch["_obs_n"] = take
-                    fs.arrival[0] = pull.t1
-                fs.source_index += take
-                remaining[fs.name] -= take
-                for g in fs.groups:
-                    p = g.start(batch)
-                    if p is not None:
-                        fs.pendings.append((g, p))
-                    elif self._chaos:
-                        self._ticket_done(fs, batch)
-                progressed = True
-            if self.pipelined:
-                # overlap: ship the queue when the coalescing window fills
-                # (or every feed is parked), harvest whatever the device
-                # finished while this round did host-side work, resume
-                # those continuations, and block only when truly stalled
-                self.server.pump(
-                    progressed, self.coalesce_frames,
-                    lambda: sum(self._settle(fs) for fs in self._feeds))
-            elif self.server.pending_frames() >= self.coalesce_frames \
-                    or not progressed:
-                self._drain_all()                 # lock-step baseline
-            rnd += 1
-        self._drain_all()
+        handed = threading.Condition()
         for fs in self._feeds:
-            if self._chaos and fs.breaker is not None \
-                    and not fs.breaker.closed:
-                # still quarantined at end of run: window aggregates over
-                # the outage would cover frames the feed never served —
-                # withhold them (never wrong) instead of emitting
-                # partial answers
-                continue
-            for g in fs.groups:
-                g.flush()
-        wall = time.perf_counter() - t0
+            fs.handover = _Handover(fs.name, fs.feed.stream, handed)
+        try:
+            rnd = 0
+            while any(remaining.values()) or \
+                    any(fs.pendings for fs in self._feeds):
+                order = self._feeds[rnd % len(self._feeds):] + \
+                    self._feeds[:rnd % len(self._feeds)]
+                progressed = False
+                for fs in order:
+                    progressed = self._turn(fs, remaining, rnd) or progressed
+                if self.pipelined:
+                    # overlap: ship the queue when the coalescing window
+                    # fills (or no feed took a micro-batch), harvest
+                    # whatever the device finished while this round did
+                    # host-side work, resume those continuations, and
+                    # block only when truly stalled
+                    self.server.pump(
+                        progressed, self.coalesce_frames,
+                        lambda: sum(self._settle(fs) for fs in self._feeds))
+                elif self.server.pending_frames() >= self.coalesce_frames \
+                        or not progressed:
+                    self._drain_all()             # lock-step baseline
+                if not progressed and self._idle():
+                    self._await_handover(handed, order, remaining, rnd)
+                rnd += 1
+            self._drain_all()
+            for fs in self._feeds:
+                if self._chaos and fs.breaker is not None \
+                        and not fs.breaker.closed:
+                    # still quarantined at end of run: window aggregates
+                    # over the outage would cover frames the feed never
+                    # served — withhold them (never wrong) instead of
+                    # emitting partial answers
+                    continue
+                for g in fs.groups:
+                    g.flush()
+            wall = time.perf_counter() - t0
+        finally:
+            for fs in self._feeds:
+                fs.handover.close()
+                fs.handover = None
 
         return self._collect(frames_by_feed, mllm_start, wall)
+
+    def _turn(self, fs: _FeedState, remaining: Dict[str, int],
+              rnd: int, pull=None) -> bool:
+        """The feed's turn in a round; whether it made progress.  ``pull``
+        is the open ``ingest`` span of an idle wait (see ``_take``)."""
+        if self._chaos:
+            ct = self._chaos_turn(fs, remaining)
+            if ct is not None:              # trip / quarantine / probe
+                return ct
+        if remaining[fs.name] <= 0:
+            return False
+        if len(fs.pendings) >= self.max_pending * len(fs.groups):
+            return False                          # per-stream backpressure
+        if self._chaos and not fs.tickets and not fs.pendings \
+                and rnd % self.snapshot_every == 0:
+            self._snap_feed(fs)                   # opportunistic, quiescent
+        ho = fs.handover
+        if not ho.ready:
+            ho.request(min(self.micro_batch, remaining[fs.name]))
+            if ho.live:
+                return False      # frames not due yet: sit this round out
+        return self._take(fs, remaining, pull)
+
+    def _idle(self) -> bool:
+        """Nothing to launch, harvest or resume: only frames can come."""
+        return not (self.server.pending_requests() or self.server.inflight
+                    or any(fs.pendings for fs in self._feeds))
+
+    def _await_handover(self, handed: threading.Condition,
+                        order: List[_FeedState],
+                        remaining: Dict[str, int], rnd: int) -> None:
+        """Block until a feed's hand-over fills, then give that feed its
+        turn: the wait is part of the ``ingest`` span of the micro-batch
+        that ended it.  A micro-batch already handed over (a stalled turn
+        left it) waits for the next round instead."""
+        asked = [fs for fs in order if fs.handover.pending]
+        if not asked or any(fs.handover.ready for fs in asked):
+            return
+        # the feed is known once the wait ends; ``_take`` moves the span
+        # to that feed's track
+        pull = span(self.obs, "ingest", "ingest", "runtime")
+        try:
+            with handed:
+                handed.wait_for(lambda: any(fs.handover.ready
+                                            for fs in asked))
+            fs = next(fs for fs in asked if fs.handover.ready)
+            self._turn(fs, remaining, rnd, pull)
+        finally:
+            pull.close()             # a no-op once ``_take`` closed it
+
+    def _take(self, fs: _FeedState, remaining: Dict[str, int],
+              pull=None) -> bool:
+        """Take the feed's micro-batch from its hand-over (waiting for it
+        if it is not there yet), start it through every group and ask
+        for the next; False if the feed produced nothing.  ``pull`` is the
+        open ``ingest`` span of an idle wait this micro-batch ended; its
+        ``ready`` stat says whether the loop found the micro-batch there
+        (1) or waited for it (0)."""
+        ho = fs.handover
+        take = min(self.micro_batch, remaining[fs.name])
+        ready = pull is None and ho.ready
+        if pull is None:
+            pull = span(self.obs, "ingest", "ingest", f"feed:{fs.name}")
+        try:
+            got = self._ingest(fs, take) if self._chaos \
+                else ("ok", *ho.take())
+        finally:
+            pull.close(track=f"feed:{fs.name}", n=take, feed=fs.name,
+                       mb=fs.source_index, ready=int(ready))
+        if got[0] == "stall":
+            return False           # the feed produced nothing this round
+        if got[0] == "lost":
+            # delivery retries exhausted: quarantine first (healthy
+            # in-flight frames settle and serve), then account the lost
+            # batch itself
+            self._trip(fs, "ingest delivery retries exhausted")
+            self._degrade_range(fs, fs.source_index, fs.source_index + take)
+            fs.source_index += take
+            remaining[fs.name] -= take
+            return True
+        frames, labels = got[1], got[2]
+        fs.labels.extend(labels)
+        batch = {"frames": frames,
+                 "idx": np.arange(fs.source_index, fs.source_index + take)}
+        if self._chaos:
+            # frame-range ticket: retired once every group's fan-out for
+            # this micro-batch completes — the outstanding set defines
+            # ``served_upto``
+            fs.tickets[fs.source_index] = len(fs.groups)
+            batch["_ticket"] = fs.source_index
+        if self.obs.enabled:
+            # lifecycle stamps ride the batch dict (every op copies it, so
+            # they survive to fan-out); the shared arrival slot feeds the
+            # staleness measure
+            batch["_obs_t0"] = pull.t1
+            batch["_obs_n"] = take
+            fs.arrival[0] = pull.t1
+        fs.source_index += take
+        remaining[fs.name] -= take
+        for g in fs.groups:
+            p = g.start(batch)
+            if p is not None:
+                fs.pendings.append((g, p))
+            elif self._chaos:
+                self._ticket_done(fs, batch)
+        # asked for only now: a tail this micro-batch ran synchronously
+        # (no row reached the extract) comes before the next pull
+        if remaining[fs.name] > 0:
+            ho.request(min(self.micro_batch, remaining[fs.name]))
+        return True
 
     # ------------------------------------------------------------------
     def _collect(self, frames_by_feed: Dict[str, int],

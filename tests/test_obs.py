@@ -416,7 +416,7 @@ def test_served_spans_reach_the_profiler_trace_with_their_ids(ctx,
             "forward", "block", "harvest", "resume", "tail"} <= set(by)
     assert all(n in _SPANS + ("dispatch", "forward")
                or n.startswith("prefix:") for n in by)
-    want = {"ingest": {"feed", "mb", "n"},
+    want = {"ingest": {"feed", "mb", "n", "ready"},
             skip: {"feed", "mb", "n", "n_out", "h2d_bytes", "d2h_bytes"},
             pre: {"feed", "mb", "n", "n_out", "h2d_bytes", "d2h_bytes"},
             "queue_wait": {"feed", "mb", "req", "n", "fwd"},
@@ -434,6 +434,8 @@ def test_served_spans_reach_the_profiler_trace_with_their_ids(ctx,
     frame = 3 * 128 * 256
     assert sorted(st["mb"] for _, _, st in by["ingest"]
                   if st["feed"] == "tb0") == [0, 16]
+    # whether the hand-over held the micro-batch when the loop came for it
+    assert {st["ready"] for _, _, st in by["ingest"]} <= {0, 1}
     for _, _, st in by[skip]:
         assert st["h2d_bytes"] == 2 * st["n"] * frame
         assert st["d2h_bytes"] == st["n"] * 4 * 8 * 4

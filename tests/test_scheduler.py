@@ -10,6 +10,10 @@ fewer forwards than independent execution while every query's outputs stay
 bitwise identical — plus a hypothesis property test over random catalog
 subsets (the conventions of ``test_properties.py``).
 """
+import dataclasses
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -453,3 +457,309 @@ def assert_sharing_tree_equals_independent(ctx, qids, seed,
 ])
 def test_sharing_tree_equivalence_fixed_subsets(ctx, qids, seed):
     assert_sharing_tree_equals_independent(ctx, qids, seed)
+
+
+# ---------------------------------------------------------------------------
+# (d) the source hand-over: each feed pulled on a thread of its own
+# ---------------------------------------------------------------------------
+
+class _PacedStream:
+    """Frames rendered up front and handed over at camera rate:
+    ``batch(n)`` sleeps until the last of the ``n`` frames is due, on a
+    clock shared by the feeds of a run that starts at the first paced
+    pull.  Before the first ``reset()`` (the runtime's warm-up) it hands
+    frames over at once.  ``handed`` logs every paced hand-over as
+    ``(t_ns, first frame, ns after the last frame was due)``."""
+
+    def __init__(self, stream, n, fps, phase_s, clock):
+        self.frames, self.labels = stream.batch(n)
+        self.fps, self.phase_s, self.clock = fps, phase_s, clock
+        self.i = 0
+        self.paced = False
+        self.handed = []
+
+    def due_ns(self, i):
+        return self.clock["t0"] + int((self.phase_s + i / self.fps) * 1e9)
+
+    def batch(self, n):
+        i = self.i
+        if self.paced:
+            self.clock.setdefault("t0", time.perf_counter_ns())
+            wait = self.due_ns(i + n - 1) - time.perf_counter_ns()
+            if wait > 0:
+                time.sleep(wait / 1e9)
+            t = time.perf_counter_ns()
+            self.handed.append((t, i, t - self.due_ns(i + n - 1)))
+        self.i = i + n
+        return self.frames[i:i + n], self.labels[i:i + n]
+
+    def reset(self):
+        self.i = 0
+        self.paced = True
+
+
+@dataclasses.dataclass
+class _StampSink(SinkOp):
+    """The sink, logging the clock of every call on a micro-batch (the
+    end-of-stream flush carries no frames of the stream's shape); the
+    warm-up's calls come before the clock's ``t0``."""
+
+    def __post_init__(self):
+        super().__post_init__()
+        self.calls = []
+
+    def process(self, batch):
+        if batch["frames"].ndim == 4 and batch["frames"].shape[1] > 1:
+            self.calls.append(time.perf_counter_ns())
+        return super().process(batch)
+
+
+def _stamped(qid):
+    plan = get_query(qid).naive_plan()
+    return Plan(plan.ops[:-1] + [_StampSink()], query=qid)
+
+
+def _calls(ms, feed, clock):
+    """Per query, the clock of each sink call of the measured run."""
+    return {q: [t for t in tail[-1].calls if t >= clock["t0"]]
+            for g in ms.forests[feed].groups()
+            for q, tail in zip(g.execution.queries, g.execution.tails)}
+
+
+def _warm_buckets(ms, shape=(3, 128, 256)):
+    """Compile the extract at every bucket a run may coalesce into, so no
+    compile stalls the paced window (the runtime's warm-up compiles one
+    micro-batch per feed)."""
+    for b in (8, 16, 32):
+        ms.server.submit("big", np.zeros((b,) + shape, np.uint8))
+        ms.server.drain()
+
+
+def _handover_threads():
+    return [t for t in threading.enumerate()
+            if t.name.startswith("handover:") and t.is_alive()]
+
+
+@pytest.mark.slow
+def test_one_paced_feed_is_answered_before_its_next_micro_batch(ctx):
+    # at one feed the loop no longer sleeps in the next pull while a
+    # micro-batch waits: each micro-batch's forward launches and its
+    # answers reach every sink while the next one is still being filled
+    # (8-frame micro-batches every 0.6 s; a CPU forward of 8 takes ~0.1 s).
+    # The loop waits idle for every micro-batch, and each wait's ``ingest``
+    # span lies on the feed's track
+    from repro.obs import Observability
+
+    clock = {}
+    src = _PacedStream(TollBoothStream(seed=42), 32, fps=8 / 0.6,
+                       phase_s=0.0, clock=clock)
+    obs = Observability(slo_target_ms=10_000.0)
+    ms = MultiStreamRuntime(
+        [Feed("tb0", src, [_stamped(q) for q in ("Q2", "Q6")])],
+        dataclasses.replace(ctx, obs=obs), micro_batch=8)
+    res = ms.run(32)
+    assert [i for _, i, _ in src.handed] == [0, 8, 16, 24]
+    for qid, calls in _calls(ms, "tb0", clock).items():
+        assert len(calls) == 4, qid
+        for k in range(3):
+            assert calls[k] < src.handed[k + 1][0], (qid, k)
+        ind = StreamRuntime(get_query(qid).naive_plan(), ctx,
+                            micro_batch=8).run(TollBoothStream(seed=42), 32)
+        assert res.feeds["tb0"].per_query[qid].outputs == ind.outputs
+    # one forward per micro-batch: none waited for the next to coalesce
+    assert res.server_stats["forwards"] == 4
+    ingest = [e for e in obs.tracer.events() if e["name"] == "ingest"]
+    assert [(e["track"], e["n"]) for e in ingest] == [("feed:tb0", 8)] * 4
+    assert not _handover_threads()
+
+
+@pytest.mark.slow
+def test_two_paced_feeds_are_each_taken_at_their_own_rate(ctx):
+    # a fast feed and one at half its rate: the loop takes each as it is
+    # handed over, so neither is paced by the other's pulls (pulled in
+    # rounds, the fast feed would fall 0.6 s further behind every round);
+    # every (frame, query) is answered and the outputs equal independent
+    # execution
+    clock = {}
+    fast = _PacedStream(TollBoothStream(seed=7), 48, fps=8 / 0.6,
+                        phase_s=0.05, clock=clock)
+    slow = _PacedStream(VolleyballStream(seed=5), 24, fps=8 / 1.2,
+                        phase_s=0.35, clock=clock)
+    feeds = [Feed("tb", fast, [_stamped(q) for q in ("Q2", "Q8")]),
+             Feed("vb", slow, [_stamped(q) for q in ("Q12", "Q13")])]
+    ms = MultiStreamRuntime(feeds, ctx, micro_batch=8)
+    _warm_buckets(ms)
+    res = ms.run({"tb": 48, "vb": 24})
+    for src, n in ((fast, 48), (slow, 24)):
+        assert [i for _, i, _ in src.handed] == list(range(0, n, 8))
+        period = int(8 / src.fps * 1e9)
+        assert max(late for _, _, late in src.handed) < period
+    for name, make, n in (("tb", lambda: TollBoothStream(seed=7), 48),
+                          ("vb", lambda: VolleyballStream(seed=5), 24)):
+        for qid, calls in _calls(ms, name, clock).items():
+            assert len(calls) == n // 8, qid
+            ind = StreamRuntime(get_query(qid).naive_plan(), ctx,
+                                micro_batch=8).run(make(), n)
+            shared = res.feeds[name].per_query[qid]
+            assert shared.outputs == ind.outputs
+            assert shared.window_results == ind.window_results
+    assert not _handover_threads()
+
+
+class _Broken(RuntimeError):
+    pass
+
+
+class _FailingStream(TollBoothStream):
+    """Raises on the pull of frame ``fail_at`` once the run is under way."""
+
+    def __init__(self, fail_at, **kw):
+        super().__init__(**kw)
+        self.fail_at = fail_at
+        self.paced = False
+
+    def batch(self, n):
+        if self.paced and self._index >= self.fail_at:
+            raise _Broken(f"source broke at frame {self._index}")
+        return super().batch(n)
+
+    def reset(self, seed=None):
+        super().reset(seed)
+        self.paced = True
+
+
+@pytest.mark.slow
+def test_no_handover_thread_outlives_a_run_that_raises(ctx, monkeypatch):
+    from repro.faults import ExtractStallError
+
+    # a source that throws: re-raised on the loop thread, threads joined
+    ms = MultiStreamRuntime(
+        [Feed("tb0", _FailingStream(32, seed=2),
+              [get_query("Q2").naive_plan()]),
+         Feed("vb0", VolleyballStream(seed=5),
+              [get_query("Q12").naive_plan()])], ctx, micro_batch=16)
+    with pytest.raises(_Broken, match="frame 32"):
+        ms.run(64)
+    assert not _handover_threads()
+
+    # the server's watchdog firing mid-run, while a paced pull sleeps
+    clock = {}
+    src = _PacedStream(TollBoothStream(seed=42), 64, fps=40.0, phase_s=0.0,
+                       clock=clock)
+    ms = MultiStreamRuntime(
+        [Feed("tb0", src, [get_query("Q2").naive_plan()])], ctx,
+        micro_batch=16)
+    pumped = []
+
+    def stalled(*a, **kw):
+        pumped.append(1)
+        raise ExtractStallError("forced")
+
+    monkeypatch.setattr(ms.server, "pump", stalled)
+    with pytest.raises(ExtractStallError, match="forced"):
+        ms.run(64)
+    assert pumped == [1]
+    assert not _handover_threads()
+
+
+def test_handover_hands_every_frame_over_once_in_order():
+    # model-free stress: sixteen hand-over threads (more than a small host
+    # has cores), the interpreter switching threads as often as it can,
+    # the loop taking feeds in a shuffled order — every frame of every
+    # feed arrives once, in order, and no thread outlives close()
+    import sys
+
+    from repro.scheduler.multistream import _Handover
+
+    class Counting:
+        def __init__(self):
+            self.i = 0
+
+        def batch(self, n):
+            out = np.arange(self.i, self.i + n)
+            self.i += n
+            return out, [None] * n
+
+    rng = np.random.default_rng(0)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        cond = threading.Condition()
+        hos = [_Handover(f"s{k}", Counting(), cond)
+               for k in range(16)]
+        got = [[] for _ in hos]
+        deadline = time.monotonic() + 20
+        rounds = 0
+        while rounds < 200 and time.monotonic() < deadline:
+            for k in rng.permutation(len(hos)):
+                hos[k].request(int(rng.integers(1, 5)))
+                if rng.random() < 0.7:
+                    got[k].append(hos[k].take()[0])
+            rounds += 1
+        for k, ho in enumerate(hos):
+            if ho.pending:
+                got[k].append(ho.take()[0])
+            ho.close()
+    finally:
+        sys.setswitchinterval(old)
+    assert rounds > 10
+    for frames in got:
+        seen = np.concatenate(frames)
+        assert np.array_equal(seen, np.arange(len(seen)))
+    assert not _handover_threads()
+
+
+@pytest.mark.slow
+@pytest.mark.chaos
+@pytest.mark.parametrize("trip", [
+    # a corrupt delivery past the retry budget (nothing pulled ahead)
+    ("source", "corrupt", 3),
+    # a forward failing past its retry budget, while the feed's next
+    # micro-batch is being pulled or waits in the hand-over
+    ("forward", "error", 1),
+])
+def test_paced_feed_under_faults_serves_every_frame_once(ctx, trip):
+    # the waiting path under fault injection: a paced feed stalls once,
+    # then a fault trips its breaker; it probes, replays from its
+    # snapshot and serves the rest of the stream.  Every served answer
+    # equals independent execution at the same frame, no frame is
+    # answered twice, the frames partition exactly, the healthy paced
+    # feed never notices, and no hand-over thread is left
+    from repro.faults import FaultInjector, FaultRule
+
+    site, kind, event = trip
+    clock = {}
+    tb = _PacedStream(TollBoothStream(seed=42), 48, fps=8 / 0.3,
+                      phase_s=0.0, clock=clock)
+    vb = _PacedStream(VolleyballStream(seed=5), 48, fps=8 / 0.3,
+                      phase_s=0.1, clock=clock)
+    inj = FaultInjector(seed=11, rules=[
+        FaultRule(site="source", kind="stall", feed="tb0", start=1,
+                  count=1),
+        FaultRule(site=site, kind=kind, feed="tb0", start=event,
+                  count=1 if site == "forward" else 2, param=99)])
+    ms = MultiStreamRuntime(
+        [Feed("tb0", tb, [get_query("Q2").naive_plan()]),
+         Feed("vb0", vb, [get_query("Q12").naive_plan()])],
+        ctx, micro_batch=8, faults=inj, breaker_cooldown=1)
+    res = ms.run(48)
+    fired = {(e["feed"], e["kind"], e["event"]) for e in inj.log}
+    assert {("tb0", "stall", 1), ("tb0", kind, event)} <= fired
+    fr = res.feeds["tb0"]
+    assert fr.breaker["trips"] == 1 and fr.breaker["recoveries"] >= 1
+    assert fr.served + fr.degraded + fr.dropped == 48
+    assert 0 < fr.degraded + fr.dropped <= 24
+    for name, make, qid in (("tb0", lambda: TollBoothStream(seed=42), "Q2"),
+                            ("vb0", lambda: VolleyballStream(seed=5),
+                             "Q12")):
+        ind = StreamRuntime(get_query(qid).naive_plan(), ctx,
+                            micro_batch=8).run(make(), 48)
+        want = {r["idx"]: r for r in ind.outputs}
+        got = res.feeds[name].per_query[qid].outputs
+        assert len({r["idx"] for r in got}) == len(got)
+        assert all(want[r["idx"]] == r for r in got)
+    assert res.feeds["vb0"].per_query["Q12"].outputs == \
+        StreamRuntime(get_query("Q12").naive_plan(), ctx,
+                      micro_batch=8).run(VolleyballStream(seed=5), 48).outputs
+    assert res.feeds["vb0"].served == 48
+    assert not _handover_threads()
