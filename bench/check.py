@@ -186,13 +186,16 @@ def ref_input_fn(cfg, feeds):
     return ref_input
 
 
-def compare(fleet, cfg, feeds, run_result, window_unanswered, refs,
+def compare(fleet, cfg, feeds, run_result, window_unanswered, model,
             params, seed_words, control=None):
     """The compared numbers of the program, and, where ``control`` (the
     extract's lower-precision path, see ``extract_gap``) is given, the
     same numbers with the control in the program's place: its answers on
-    the sampled rows and its preprocess on every transformed frame.
-    Returns ``(program, control_or_None)``."""
+    the sampled rows and its preprocess on every transformed frame.  The
+    extract's reference is the configuration's own, ``model.reference``
+    (``layout.model``).  Returns ``(program, control_or_None)``."""
+    refs = {v: model.reference(spec, cfg["patch"])
+            for v, spec in cfg["backbone"].items()}
     served, keep_bad = _served_rows(fleet, cfg, feeds)
     picked = sample_rows(served, int(cfg["check"]["rows"]), seed_words)
     gap, gap_control = extract_gap(served, picked, refs, params,

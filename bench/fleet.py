@@ -142,6 +142,8 @@ def make_server_class():
 
 # ------------------------------------------------------------------ models
 def arch_config(spec: Dict[str, Any]):
+    """A dense GQA ``backbone`` entry as the program takes it (exported by
+    the configuration modules of the dense backbones)."""
     from repro.common.config import ArchConfig, AttentionConfig
     kw = dict(spec)
     kw["attention"] = AttentionConfig(**kw["attention"])
@@ -154,7 +156,8 @@ def fan_in(name: str, shape) -> int:
     attention projections contract over the model width (``wq``/``wk``/
     ``wv``: ``(d, heads, head_dim)``) or over heads × head_dim (``wo``),
     a conv over its window × input channels, every other matrix over its
-    second-to-last axis.  Stacked layers add a leading axis."""
+    second-to-last axis.  Stacked layers add a leading axis.  The rule of
+    the dense GQA backbone, exported by its configuration modules."""
     if name in ("wq", "wk", "wv"):
         return shape[-3]
     if name == "wo":
@@ -164,7 +167,7 @@ def fan_in(name: str, shape) -> int:
     return shape[-2] if len(shape) >= 2 else shape[-1]
 
 
-def _init_leaf(name: str, p, key, dtype):
+def _init_leaf(name: str, p, key, dtype, fan_in):
     import jax
     import jax.numpy as jnp
     if p.init == "zeros":
@@ -180,11 +183,13 @@ def _init_leaf(name: str, p, key, dtype):
     return jax.random.normal(key, p.shape, dtype) * jnp.asarray(std, dtype)
 
 
-def init_weights(mllm, max_patches: int, seed: int, salt: int, dtype):
+def init_weights(mllm, max_patches: int, seed: int, salt: int, dtype,
+                 fan_in):
     """Every weight the extract reads, on the device, in one jitted call
     from the seed (the backbone's token embedding and LM head are not
     held: the extract reads neither).  Normal with LeCun fan-in scaling
-    (``fan_in``), so activations and attention scores keep unit scale."""
+    (``fan_in(name, shape)``, the configuration's own), so activations
+    and attention scores keep unit scale."""
     import jax
     from repro.models.param import ParamSpec
 
@@ -202,7 +207,7 @@ def init_weights(mllm, max_patches: int, seed: int, salt: int, dtype):
     def make(key):
         keys = jax.random.split(key, len(leaves))
         return jax.tree_util.tree_unflatten(
-            treedef, [_init_leaf(n, p, k, dtype)
+            treedef, [_init_leaf(n, p, k, dtype, fan_in)
                       for n, p, k in zip(names, leaves, keys)])
 
     return make(jax.random.key(k32))

@@ -71,7 +71,10 @@ class CompileLog:
         return sum(1 for t in self.times if a <= t <= b)
 
 
-def make_context(cfg: Dict[str, Any], seed: int):
+def make_context(cfg: Dict[str, Any], seed: int, model):
+    """The program's extract context and its seeded weights per variant;
+    ``model`` is the configuration's module (``layout.model``), which
+    gives the backbone and the fan-in of its weights."""
     import jax.numpy as jnp
     from repro.streaming.mllm import StreamMLLM
     from repro.streaming.operators import OpContext
@@ -80,10 +83,10 @@ def make_context(cfg: Dict[str, Any], seed: int):
         else jnp.float32
     models, params = {}, {}
     for salt, (variant, spec) in enumerate(sorted(cfg["backbone"].items())):
-        m = StreamMLLM(fleetlib.arch_config(spec), patch=cfg["patch"])
+        m = StreamMLLM(model.arch_config(spec), patch=cfg["patch"])
         models[variant] = m
         params[variant] = fleetlib.init_weights(m, cfg["max_patches"], seed,
-                                                salt, dtype)
+                                                salt, dtype, model.fan_in)
     ctx = OpContext(mllm=models.get("big"), mllm_params=params.get("big"),
                     mllm_small=models.get("small"),
                     mllm_small_params=params.get("small"),
@@ -156,7 +159,7 @@ def _run(cell, cfg, seed, seconds, trace, t_process_ns, jax, devs, peak,
     cell_name = cell["name"]
     kind = devs[0].device_kind
     t = time.perf_counter()
-    ctx, params = make_context(cfg, seed)
+    ctx, params = make_context(cfg, seed, cell["model"])
     jax.block_until_ready(params)
     t_w = time.perf_counter() - t
     t = time.perf_counter()
@@ -223,11 +226,8 @@ def _run(cell, cfg, seed, seconds, trace, t_process_ns, jax, devs, peak,
          f"compiles in window {n_compiles}")
 
     # ---- the check, after the window, with the program's state released
-    from reference import Reference
-    refs = {v: Reference(spec_, cfg["patch"])
-            for v, spec_ in cfg["backbone"].items()}
     program, ctl = check.compare(
-        fl, cfg, feeds, res, win["unanswered"], refs, params,
+        fl, cfg, feeds, res, win["unanswered"], cell["model"], params,
         fleetlib.seed_words(seed),
         fleetlib.lower_precision_extract(ctx) if control else None)
     compared = ctl if control else program

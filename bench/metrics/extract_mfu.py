@@ -6,7 +6,7 @@ def read(run):
     tr = run["trace"]
     if tr["window_s"] <= 0:
         return None
-    mod = run["cell"]["flops"]
+    mod = run["cell"]["model"]
     cfg = run["config"]
     flops = 0
     for _, req in run["requests"]:

@@ -19,7 +19,9 @@ written from the operator and model descriptions:
   and tumbling windows by frame index that close when a later record
   arrives and flush a partial window at the end of the stream.
 
-``Reference`` computes in float32 at the highest matmul precision.
+``Reference`` computes in float32 at the highest matmul precision.  It is
+the dense GQA decoder's reference: a configuration's module
+(``configs/<config>.py: reference``) names it, or brings its own.
 """
 from __future__ import annotations
 

@@ -26,7 +26,8 @@ def test_every_cell_config_traffic_and_metric_is_found_by_name():
         assert cell["config_spec"]["name"] == w["config"]
         assert os.path.exists(os.path.join(layout.ROOT,
                                            configs[w["config"]]["file"]))
-        assert callable(cell["flops"].flops_per_frame)
+        for fn in layout.MODEL_FUNCTIONS:
+            assert callable(getattr(cell["model"], fn))
     for m in spec["per_layer"]:
         assert callable(layout.metric_reader(m["name"]).read)
 
